@@ -37,6 +37,8 @@ def main() -> int:
                          "trajectory append per run id)")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (beyond_paper, chaos_bench, cluster_sim,
                             fabric_bench, fig10_utilization,
                             fig11_switch_overhead, fig12_traffic,

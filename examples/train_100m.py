@@ -1,12 +1,11 @@
 """End-to-end driver: train a ~100M-param model for a few hundred steps.
 
-This is the deliverable-(b) scale run (CPU-sized batch; the same code
-drives the production mesh on real hardware via launch/train.py).
+This is the deliverable-(b) scale run (CPU-sized batch; the same code,
+launch/train.py, runs it on one chip on a TPU host).
 
     PYTHONPATH=src python examples/train_100m.py --steps 200
 """
 import argparse
-import sys
 
 from repro.launch import train as train_cli
 
@@ -16,8 +15,7 @@ def main():
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--arch", default="qwen2-0.5b")
     args = ap.parse_args()
-    sys.argv = [
-        "train",
+    return train_cli.main([
         "--arch", args.arch,
         "--preset", "100m",
         "--steps", str(args.steps),
@@ -27,8 +25,7 @@ def main():
         "--ckpt-every", "50",
         "--resume", "auto",
         "--log-every", "10",
-    ]
-    return train_cli.main()
+    ])
 
 
 if __name__ == "__main__":

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -100,7 +98,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, block_q: int = 256,
-                    block_k: int = 256, interpret: bool = True):
+                    block_k: int = 256, interpret: bool):
     """q (B,S,H,D); k/v (B,T,K,D) -> (B,S,H,D).  H = K·G (GQA)."""
     B, S, H, D = q.shape
     T, K = k.shape[1], k.shape[2]
@@ -138,7 +136,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((G * bq,), jnp.float32),       # running denom
             pltpu.VMEM((G * bq, D), jnp.float32),     # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

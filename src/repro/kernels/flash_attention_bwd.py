@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -258,7 +256,7 @@ def _fwd(q, k, v, *, causal, window, softcap, bq, bk, interpret):
             pltpu.VMEM((G * bq,), jnp.float32),
             pltpu.VMEM((G * bq, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -303,7 +301,7 @@ def _bwd(q, k, v, o_blk, m, l, dout, *, causal, window, softcap, bq, bk,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -328,7 +326,7 @@ def _bwd(q, k, v, o_blk, m, l, dout, *, causal, window, softcap, bq, bk,
                                lambda b, h, i, j: (b, h, 0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, G, S, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((G * bq, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -345,8 +343,8 @@ def _bwd(q, k, v, o_blk, m, l, dout, *, causal, window, softcap, bq, bk,
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def flash_attention_vjp(q, k, v, causal=True, window=0, softcap=0.0,
-                        block_q=256, block_k=256, interpret=True):
+def flash_attention_vjp(q, k, v, causal, window, softcap, block_q, block_k,
+                        interpret):
     """Differentiable fused flash attention (Pallas fwd + bwd kernels)."""
     out, _ = _fwd(q, k, v, causal=causal, window=window, softcap=softcap,
                   bq=min(block_q, q.shape[1]),

@@ -44,8 +44,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -100,7 +98,7 @@ def _paged_kernel(tables_ref, lengths_ref, q_ref, *refs, ppb: int, ps: int,
 
 def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
                            block_k: int = 256, softcap: float = 0.0,
-                           interpret: bool = True):
+                           interpret: bool):
     """q (B,H,D) one decode token/seq; k/v pages (N,ps,K,D); tables (B,P)
     int32 page ids; lengths (B,) valid-token counts -> (B,H,D).
 
@@ -152,7 +150,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tables, lengths, qg, *([k_pages] * ppb), *([v_pages] * ppb))

@@ -27,7 +27,8 @@ Registry file schema (``results/tuned_configs.json`` by default, or
                            "backend": "cpu"}}}
 
 Lookups that miss fall back to the caller's defaults — an empty or absent
-registry reproduces the pre-tuning behaviour exactly.
+registry reproduces the pre-tuning behaviour exactly.  An entry whose
+``backend`` differs from ``jax.default_backend()`` is a miss.
 """
 from __future__ import annotations
 
@@ -36,6 +37,8 @@ import json
 import os
 import threading
 from typing import Any, Dict, Mapping, Optional, Tuple
+
+import jax
 
 DEFAULT_PATH = os.path.join("results", "tuned_configs.json")
 ENV_VAR = "REPRO_TUNED_CONFIGS"
@@ -112,10 +115,14 @@ class Registry:
 
     def lookup(self, kernel: str, defaults: Mapping[str, int], *,
                dtype: str, variant: str = "", **dims: int) -> Dict[str, int]:
-        """Tuned blocks for the cell, or ``defaults`` on a miss."""
+        """Tuned blocks for the cell, or ``defaults`` on a miss.
+
+        An entry tuned on another backend is a miss: blocks that the CPU
+        interpreter ranked say nothing about the TPU."""
         entry = self.get(make_key(kernel, dtype=dtype, variant=variant,
                                   **dims))
-        if entry is None:
+        if entry is None or entry.backend not in ("",
+                                                  jax.default_backend()):
             return dict(defaults)
         out = dict(defaults)
         out.update(entry.blocks)

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _rglru_kernel(log_a_ref, gated_ref, y_ref, h_ref, *, bs: int):
     j = pl.program_id(1)
@@ -44,7 +42,7 @@ def _rglru_kernel(log_a_ref, gated_ref, y_ref, h_ref, *, bs: int):
     h_ref[...] = jax.lax.fori_loop(0, bs, step, h_ref[...])
 
 
-def rglru(log_a, gated, *, block_seq: int = 128, interpret: bool = True):
+def rglru(log_a, gated, *, block_seq: int = 128, interpret: bool):
     """Linear recurrence h_t = exp(log_a_t)·h_{t-1} + gated_t.
 
     log_a/gated (B, S, W) -> hs (B, S, W) fp32.
@@ -65,7 +63,7 @@ def rglru(log_a, gated, *, block_seq: int = 128, interpret: bool = True):
         out_specs=pl.BlockSpec((1, bs, W), lambda b, j: (b, j, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), jnp.float32),
         scratch_shapes=[pltpu.VMEM((W,), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, gated)

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
                 h_ref, *, chunk: int, nc: int, hpg: int):
@@ -86,7 +84,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
         hout_ref[0] = h_new.astype(hout_ref.dtype)
 
 
-def ssd(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool = True):
+def ssd(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool):
     """Chunked SSD scan.
 
     x (B,S,H,P); dt (B,S,H); A (H,); B/C (B,S,G,N).
@@ -119,7 +117,7 @@ def ssd(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool = True):
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((H, N, P), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, A, Bm, Cm)

@@ -3,7 +3,8 @@
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
       --requests 8 --max-new 16      # paged engine, continuous batching
   PYTHONPATH=src python -m repro.launch.serve --no-fused ...  # legacy
-  PYTHONPATH=src python -m repro.launch.serve --no-reduced ...  # full
+  PYTHONPATH=src python -m repro.launch.serve --no-reduced \
+      --prompt-len 512 --min-prompt-len 64 --max-new 32 --max-seq 1024
 
 The paged engine warms up (pre-compiles its jit traces) before serving
 so TTFT/TPOT percentiles measure steady state; compile time is printed
@@ -20,24 +21,31 @@ from __future__ import annotations
 
 import argparse
 import time
-from collections import deque
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
+import numpy as np
 
 from repro.configs import get_config, reduced
 from repro.configs.base import PolicyConfig
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.serve import AsyncServeEngine, ServeRequest
 
 
-def main() -> int:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
                     default=True)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=32)
+    ap.add_argument("--prompt-len", type=int, default=32,
+                    help="prompt length, or the longest with "
+                         "--min-prompt-len")
+    ap.add_argument("--min-prompt-len", type=int, default=0,
+                    help="draw prompt lengths uniformly from "
+                         "[min, --prompt-len] (0 = all --prompt-len)")
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--page-size", type=int, default=16)
@@ -59,13 +67,31 @@ def main() -> int:
                     help="per-request deadline in seconds (0 = none); "
                          "timed-out requests are cancelled and reported "
                          "instead of hanging the driver")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def make_prompts(args: argparse.Namespace, vocab: int) -> List[List[int]]:
+    """Seeded prompts: ``--requests`` of them, lengths from
+    ``[--min-prompt-len, --prompt-len]``."""
+    rng = np.random.default_rng(0)
+    lo = args.min_prompt_len or args.prompt_len
+    lens = rng.integers(lo, args.prompt_len + 1, size=args.requests)
+    return [rng.integers(0, vocab, size=int(n)).tolist() for n in lens]
+
+
+def run(args: argparse.Namespace) -> Dict[str, Any]:
+    """Serve the prompts.  Returns ``exit_code`` (0 when every request
+    was served), ``outputs`` (generated tokens per request id) and the
+    engine's ``report``."""
     if args.prompt_len + args.max_new > args.max_seq:
         print(f"error: prompt ({args.prompt_len}) + max-new "
               f"({args.max_new}) tokens exceed --max-seq ({args.max_seq}); "
               f"raise --max-seq or shorten the request")
-        return 2
+        return {"exit_code": 2}
+    if not 0 <= args.min_prompt_len <= args.prompt_len:
+        print(f"error: --min-prompt-len ({args.min_prompt_len}) must lie "
+              f"in [0, --prompt-len ({args.prompt_len})]")
+        return {"exit_code": 2}
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -81,18 +107,13 @@ def main() -> int:
     if args.warmup and eng.mode == "paged":
         print(f"warmup: compiled paged step in {eng.warmup():.1f}s")
 
-    pending = deque(
-        ServeRequest(i, list(map(int, jax.random.randint(
-            jax.random.PRNGKey(i), (args.prompt_len,), 0,
-            cfg.vocab_size))), max_new=args.max_new)
-        for i in range(args.requests))
-    reqs = list(pending)
+    reqs = [ServeRequest(i, p, max_new=args.max_new)
+            for i, p in enumerate(make_prompts(args, cfg.vocab_size))]
     t0 = time.time()
-    while pending:
-        req = pending.popleft()
+    for req in reqs:
         if not eng.submit(req):
             print(f"error: request {req.rid} rejected: {req.why_rejected}")
-            return 2
+            return {"exit_code": 2}
     eng.run()
     dt = time.time() - t0
 
@@ -112,14 +133,22 @@ def main() -> int:
               f"evictions={kv['evictions']}")
     for r in reqs[:3]:
         print(f"  req {r.rid}: {r.out[:8]}...")
+    out = {"outputs": {r.rid: list(r.out) for r in reqs}, "served": done,
+           "report": rep, "exit_code": 0 if done == len(reqs) else 1}
     if eng.sched.cancelled:
         print(f"error: {len(eng.sched.cancelled)}/{len(reqs)} requests "
               f"timed out (--request-timeout {args.request_timeout:g}s):")
         for r in eng.sched.cancelled:
             print(f"  req {r.rid}: {r.why_rejected} "
                   f"({len(r.out)}/{r.max_new} tokens generated)")
-        return 3
-    return 0 if done == len(reqs) else 1
+        out["exit_code"] = 3
+    return out
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    args = parse_args(argv)
+    compile_cache.enable()
+    return run(args)["exit_code"]
 
 
 if __name__ == "__main__":

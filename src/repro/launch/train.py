@@ -1,10 +1,14 @@
 """End-to-end training driver.
 
 Runs a real training loop (synthetic data, AdamW, checkpoints, elastic
-restart) on whatever devices exist — single CPU for the examples/tests,
-the production mesh on real hardware.
+restart) on the default device: the CPU for the examples and tests, one
+chip on a TPU host.  The step over a mesh of several chips is
+``trainer.make_train_step(mesh=...)`` + ``trainer.jit_train_step``.
+Compile time is printed apart from step time.
 
 Examples:
+  PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
+      --steps 5 --batch 4 --seq 2048 --dtype bfloat16   # full width, 1 chip
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
       --preset 100m --steps 200 --batch 8 --seq 256 --ckpt /tmp/ck
   PYTHONPATH=src python -m repro.launch.train --arch llama3.2-3b \
@@ -17,14 +21,15 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
-from typing import Optional
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, reduced
 from repro.configs.base import ModelConfig, PolicyConfig, ShapeConfig
-from repro.data import SyntheticDataset, make_batch
+from repro.data import SyntheticDataset
+from repro.launch import compile_cache
 from repro.optim import AdamWConfig, ScheduleConfig
 from repro.train import checkpoint, trainer
 
@@ -53,7 +58,7 @@ def build(args):
     return cfg, policy, optcfg, schedcfg
 
 
-def main() -> int:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--preset", default="", choices=["", "100m"])
@@ -77,22 +82,29 @@ def main() -> int:
     ap.add_argument("--track", action="store_true",
                     help="record the run via repro.tracking "
                          "(results/runs/<run_id>/events.jsonl)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def run(args: argparse.Namespace) -> Dict[str, Any]:
+    """The training loop.  Returns ``losses`` and ``grad_norms`` (one
+    float each per step run), ``compile_s`` (lowering and compiling the
+    step, apart from the steps), ``step_s`` (per step, each ended by
+    ``block_until_ready``) and ``exit_code`` (17 after a simulated
+    failure, else 0)."""
     cfg, policy, optcfg, schedcfg = build(args)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
 
-    run = None
+    tracked = None
     if args.track:
         from repro import tracking
-        run = tracking.init(
+        tracked = tracking.init(
             f"train-{args.arch}",
             config={"arch": args.arch, "preset": args.preset,
                     "steps": args.steps, "batch": args.batch,
                     "seq": args.seq, "lr": args.lr, "dtype": args.dtype,
                     "zero": args.zero, "grad_accum": args.grad_accum},
             tags=("train",), samplers=[tracking.ProcSampler()])
-        print(f"tracking run {run.id} -> {run.path}")
+        print(f"tracking run {tracked.id} -> {tracked.path}")
     print(f"training {cfg.name}: {cfg.param_count()/1e6:.1f}M params, "
           f"batch {args.batch} x seq {args.seq}, {args.steps} steps")
 
@@ -103,14 +115,28 @@ def main() -> int:
         state, start = checkpoint.restore(args.ckpt, state)
         print(f"resumed from step {start}")
 
-    step_fn = jax.jit(trainer.make_train_step(cfg, policy, optcfg,
-                                              schedcfg, shape=shape))
+    # the state is donated: the old and the new state are never both
+    # alive, which a full-width step needs to fit one chip
     ds = SyntheticDataset(cfg, shape)
-    stepper = trainer.StepTracker(shape.tokens, run)
-    t0 = time.time()
+    t0 = time.perf_counter()
+    step_fn = jax.jit(
+        trainer.make_train_step(cfg, policy, optcfg, schedcfg, shape=shape),
+        donate_argnums=(0,)).lower(
+            state, {k: jnp.asarray(v) for k, v in
+                    ds.batch_at(start).items()}).compile()
+    out: Dict[str, Any] = {"compile_s": time.perf_counter() - t0,
+                           "losses": [], "grad_norms": [], "step_s": [],
+                           "exit_code": 0}
+    print(f"compiled train step in {out['compile_s']:.1f}s")
+    stepper = trainer.StepTracker(shape.tokens, tracked)
     for step in range(start, args.steps):
         batch = {k: jnp.asarray(v) for k, v in ds.batch_at(step).items()}
+        t = time.perf_counter()
         state, metrics = step_fn(state, batch)
+        jax.block_until_ready(metrics)
+        out["step_s"].append(time.perf_counter() - t)
+        out["losses"].append(float(metrics["loss"]))
+        out["grad_norms"].append(float(metrics["grad_norm"]))
         stepper.step(step, metrics)
         if args.ckpt and (step + 1) % args.ckpt_every == 0:
             checkpoint.save(args.ckpt, step + 1, state)
@@ -119,22 +145,31 @@ def main() -> int:
                 checkpoint.save(args.ckpt, step + 1, state)
             print(f"simulated failure at step {step + 1} — restart with "
                   f"--resume auto")
-            if run is not None:
+            if tracked is not None:
                 stepper.summary()
-                run.finish("failed")
-            return 17
+                tracked.finish("failed")
+            out["exit_code"] = 17
+            return out
         if (step + 1) % args.log_every == 0 or step == start:
             toks = shape.tokens * (step + 1 - start)
-            print(f"step {step + 1:5d}  loss {float(metrics['loss']):.4f}"
-                  f"  grad_norm {float(metrics['grad_norm']):.3f}"
-                  f"  tok/s {toks / (time.time() - t0):.0f}")
+            print(f"step {step + 1:5d}  loss {out['losses'][-1]:.4f}"
+                  f"  grad_norm {out['grad_norms'][-1]:.3f}"
+                  f"  step_s {out['step_s'][-1]:.3f}"
+                  f"  tok/s {toks / sum(out['step_s']):.0f}")
     if args.ckpt:
         checkpoint.save(args.ckpt, args.steps, state)
-    if run is not None:
+    if tracked is not None:
         stepper.summary()
-        run.finish()
-    print(f"done in {time.time() - t0:.1f}s")
-    return 0
+        tracked.finish()
+    print(f"done: {len(out['step_s'])} steps in {sum(out['step_s']):.1f}s "
+          f"(+{out['compile_s']:.1f}s compile)")
+    return out
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    args = parse_args(argv)
+    compile_cache.enable()
+    return run(args)["exit_code"]
 
 
 if __name__ == "__main__":

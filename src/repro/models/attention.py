@@ -23,7 +23,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers
-from repro.jaxcompat import shard_map
 
 
 NEG_INF = -1e30
@@ -445,15 +444,8 @@ def sharded_decode(q, k_new, v_new, cache, positions, *, mesh, dp_axes,
     manual = frozenset(dp) | ({tp_axis} if tp > 1 else set())
     if not manual:
         return None
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        already = frozenset(
-            a for a, t in zip(getattr(am, "axis_names", ()),
-                              getattr(am, "axis_types", ()))
-            if "Manual" in str(t))
-    except Exception:
-        already = frozenset()
-    out, ck, cv, cp = shard_map(
+    already = layers.manual_axes()
+    out, ck, cv, cp = jax.shard_map(
         body, mesh=None if already else mesh,
         axis_names=manual - already if already else manual,
         in_specs=(s_q, s_q, s_q, s_kv, s_kv, s_pos, s_cur),
@@ -517,15 +509,8 @@ def sharded_flash(q, k, v, *, mesh, dp_axes, tp_axis, causal=True,
     manual = frozenset(dp) | ({tp_axis} if tp > 1 else set())
     if not manual:                      # degenerate 1x1 mesh: run local
         return body(q, kr, vr)[:, :, :H]
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        already = frozenset(
-            a for a, t in zip(getattr(am, "axis_names", ()),
-                              getattr(am, "axis_types", ()))
-            if "Manual" in str(t))
-    except Exception:
-        already = frozenset()
-    out = shard_map(body, mesh=None if already else mesh,
+    already = layers.manual_axes()
+    out = jax.shard_map(body, mesh=None if already else mesh,
                         axis_names=manual - already if already else manual,
                         in_specs=(spec, spec, spec), out_specs=spec,
                         check_vma=False)(q, kr, vr)

@@ -16,6 +16,15 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def manual_axes() -> frozenset:
+    """Mesh axes already manual in the enclosing ``shard_map`` (empty
+    outside one): a nested ``shard_map`` takes the context mesh and
+    manualizes only the axes it owns beyond these."""
+    am = jax.sharding.get_abstract_mesh()
+    return frozenset(a for a, t in zip(am.axis_names, am.axis_types)
+                     if t == jax.sharding.AxisType.Manual)
+
+
 # ---------------------------------------------------------------------------
 # init helpers
 # ---------------------------------------------------------------------------
@@ -130,7 +139,9 @@ def init_embedding(key, vocab: int, d_model: int, dtype=jnp.float32):
 
 
 def embed_tokens(params, tokens, compute_dtype=jnp.bfloat16):
-    return params["table"].astype(compute_dtype)[tokens]
+    # gather, then cast: the gradient's scatter-add over the tokens then
+    # accumulates in the table's dtype, not in bf16
+    return params["table"][tokens].astype(compute_dtype)
 
 
 def unembed(params_or_table, x, compute_dtype=jnp.bfloat16):

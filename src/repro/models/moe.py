@@ -27,7 +27,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers
-from repro.jaxcompat import shard_map
 
 
 def init_moe(key, cfg: ModelConfig, dtype=jnp.float32):
@@ -240,15 +239,8 @@ def _moe_ep(params, x2d, cfg, compute_dtype, pctx, capacity):
     # is used; manualize only the axes this shard_map owns.
     kwargs = dict(in_specs=(w_spec, x_spec), out_specs=(x_spec, P()),
                   check_vma=False)
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        in_manual = am is not None and any(
-            "Manual" in str(t) for t in getattr(am, "axis_types", ()))
-    except Exception:
-        in_manual = False
-    if in_manual:
-        own = frozenset(dp + (tp,)) - frozenset(
-            a for a, t in zip(am.axis_names, am.axis_types)
-            if "Manual" in str(t))
-        return shard_map(body, axis_names=own, **kwargs)(eparams, x2d)
-    return shard_map(body, mesh=mesh, **kwargs)(eparams, x2d)
+    already = layers.manual_axes()
+    if already:
+        own = frozenset(dp + (tp,)) - already
+        return jax.shard_map(body, axis_names=own, **kwargs)(eparams, x2d)
+    return jax.shard_map(body, mesh=mesh, **kwargs)(eparams, x2d)

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers
-from repro.jaxcompat import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -134,15 +133,8 @@ def ssd_sharded(x, dt, A, Bm, Cm, *, chunk: int, mesh, dp_axes, tp_axis):
     manual = frozenset(dp) | ({tp_axis} if tp > 1 else set())
     if not manual:
         return ssd_chunked(x, dt, A, Bm, Cm, chunk=chunk)
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        already = frozenset(
-            a for a, t in zip(getattr(am, "axis_names", ()),
-                              getattr(am, "axis_types", ()))
-            if "Manual" in str(t))
-    except Exception:
-        already = frozenset()
-    return shard_map(
+    already = layers.manual_axes()
+    return jax.shard_map(
         body, mesh=None if already else mesh,
         axis_names=manual - already if already else manual,
         in_specs=(sx, sdt, sA, sBC, sBC),

@@ -30,7 +30,6 @@ from repro.core import hierarchy, policy as pol
 from repro.models import lm
 from repro.models.transformer import ParallelCtx, RunCtx
 from repro.optim import adamw, schedule
-from repro.jaxcompat import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -196,7 +195,7 @@ def make_train_step(cfg: ModelConfig, policy: PolicyConfig,
     """Returns train_step(state, batch) -> (state, metrics).
 
     Lowers/compiles under any mesh; all sharding comes from in/out specs
-    (see ``launch.dryrun`` / ``launch.train``).  ``shape`` keys the
+    (see ``jit_train_step`` and ``launch.dryrun``).  ``shape`` keys the
     tuned-config lookup for the attention tiles; None keeps defaults.
     """
     seq_len = shape.seq_len if shape is not None else None
@@ -253,7 +252,7 @@ def make_train_step(cfg: ModelConfig, policy: PolicyConfig,
             lambda x: P(*(("pod",) + (None,) * (x.ndim - 1))), batch)
         ef_spec = jax.tree.map(lambda r: P("pod"), state.ef_residual)
         gspec = jax.tree.map(lambda p: P(), state.params)
-        grads, ef_new, loss, metrics = shard_map(
+        grads, ef_new, loss, metrics = jax.shard_map(
             pod_body, mesh=mesh,
             in_specs=(gspec, ef_spec, bspec),
             out_specs=(gspec, ef_spec, P(), jax.tree.map(
@@ -267,10 +266,12 @@ def make_train_step(cfg: ModelConfig, policy: PolicyConfig,
 
 
 # ---------------------------------------------------------------------------
-# jit wiring (specs in/out) — shared by launch.train and launch.dryrun
+# jit wiring (specs in/out) for the step over a mesh of several devices
 # ---------------------------------------------------------------------------
 def jit_train_step(train_step, state: TrainState, cfg: ModelConfig,
                    policy: PolicyConfig, mesh, example_batch):
+    """``train_step`` jitted with the policy's shardings on ``mesh``; the
+    state argument is donated."""
     mesh_axes = dict(mesh.shape)
     sspec = state_specs(state, cfg, policy, mesh_axes)
     bspec = pol.batch_specs(example_batch, policy, mesh_axes)
@@ -285,7 +286,8 @@ def jit_train_step(train_step, state: TrainState, cfg: ModelConfig,
                    out_shardings=jax.tree.map(
                        lambda s: jax.sharding.NamedSharding(mesh, s)
                        if s is not None else None, out_shardings,
-                       is_leaf=lambda x: isinstance(x, P) or x is None))
+                       is_leaf=lambda x: isinstance(x, P) or x is None),
+                   donate_argnums=(0,))
 
 
 # ---------------------------------------------------------------------------

@@ -72,6 +72,23 @@ def test_registry_round_trip(tmp_path):
     assert (bq, bk) == (64, 32)
 
 
+@pytest.mark.parametrize("backend,hit", [
+    ("tpu", jax.default_backend() == "tpu"),
+    ("cpu", jax.default_backend() == "cpu"),
+    ("", True),
+])
+def test_lookup_misses_entries_tuned_on_another_backend(backend, hit):
+    reg = registry.Registry()
+    reg.put(registry.make_key("flash_attention", dtype="float32",
+                              variant="causal", s=128, t=128, d=32, g=2),
+            registry.TunedEntry(blocks={"block_q": 64, "block_k": 32},
+                                backend=backend))
+    registry.set_registry(reg)
+    got = registry.attention_blocks(128, 128, 32, 2, jnp.float32, True, 0,
+                                    defaults=(128, 128))
+    assert got == ((64, 32) if hit else (128, 128))
+
+
 def test_seq_dims_bucket_to_pow2():
     k1 = registry.make_key("flash_attention", dtype="float32",
                            variant="causal", s=384, t=384, d=64, g=4)

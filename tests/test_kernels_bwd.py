@@ -48,7 +48,8 @@ def test_flash_bwd_forward_matches_fwd_kernel():
     k = jax.random.normal(K2, (1, 128, 2, 32))
     v = jax.random.normal(K3, (1, 128, 2, 32))
     a = flash_attention_vjp(q, k, v, True, 0, 0.0, 64, 64, True)
-    b = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    b = flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                        interpret=True)
     np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
 
 

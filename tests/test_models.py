@@ -124,6 +124,21 @@ def test_chunked_xent_mask():
     np.testing.assert_allclose(a, b, atol=1e-5)
 
 
+def test_bf16_embedding_gradient_sums_a_frequent_token_in_f32():
+    """8192 uses of one token: summed in bf16, the gradient stagnates far
+    below the true sum (and then depends on how the batch is split over
+    devices); summed in the table's f32 it stays within bf16 rounding."""
+    tokens = jnp.zeros((4, 2048), jnp.int32)
+    cot = jax.random.uniform(KEY, (4, 2048, 8), minval=0.5, maxval=1.5)
+    table = jnp.zeros((16, 8), jnp.float32)
+
+    def grad(dtype):
+        return jax.grad(lambda t: jnp.sum(layers.embed_tokens(
+            {"table": t}, tokens, dtype).astype(jnp.float32) * cot))(table)
+    np.testing.assert_allclose(grad(jnp.bfloat16)[0], grad(jnp.float32)[0],
+                               rtol=2.0 ** -7)
+
+
 @given(st.integers(2, 128))
 @settings(max_examples=20, deadline=None)
 def test_gold_logit_equals_take_along_axis(V):
