@@ -1,0 +1,8 @@
+"""idle_share.train: share of the traced window in which no operation
+ran on the device, on the idlest chip, in percent (profiler trace)."""
+
+
+def read(m):
+    if m.get("kind") != "train" or "trace" not in m:
+        return None
+    return 100.0 * m["trace"]["idle_share_worst"]
