@@ -1,0 +1,12 @@
+"""attn_core_ms.train: device time per training step of the attention core
+(scores, softmax, P.V; forward, remat recompute and backward), in
+milliseconds: the window's leaf operations under the program's
+``attn_core`` scope (``bench/scopes.py``) over the steps run (profiler
+trace)."""
+
+
+def read(m):
+    if m.get("kind") != "train" or not m.get("steps"):
+        return None
+    sec = m.get("trace", {}).get("scope_s", {}).get("attn_core")
+    return None if not sec else 1e3 * sec / m["steps"]

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import layers
+from repro.models import layers, scopes
 
 
 NEG_INF = -1e30
@@ -76,6 +76,7 @@ def _mask_value(q_pos, k_pos, causal: bool, window: int):
     return m
 
 
+@scopes.scoped(scopes.ATTN_CORE)
 def full_attention(q, k, v, *, causal=True, window=0, q_offset=0,
                    kv_mask=None, softcap=0.0):
     """Oracle path. q (B,S,H,D), k/v (B,T,K,D)."""
@@ -98,6 +99,7 @@ def full_attention(q, k, v, *, causal=True, window=0, q_offset=0,
     return o.reshape(B, S, H, D)
 
 
+@scopes.scoped(scopes.ATTN_CORE)
 def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
                         q_block=512, kv_block=512, softcap=0.0,
                         batch_axes=(), head_axis=None):
@@ -205,6 +207,7 @@ def _constrain_batch(x, batch_axes, dim: int):
         return x
 
 
+@scopes.scoped(scopes.ATTN_CORE)
 def local_flash_xla(q, k, v, *, window: int, causal=True, softcap=0.0,
                     q_block=512, kv_block=512, batch_axes=(),
                     head_axis=None):
@@ -308,6 +311,7 @@ def local_flash_xla(q, k, v, *, window: int, causal=True, softcap=0.0,
     return ob.transpose(1, 0, 3, 2, 4).reshape(B, S, H, D)
 
 
+@scopes.scoped(scopes.ATTN_CORE)
 def chunk_decode_attention(q, k_cache, v_cache, cache_pos, q_pos, *,
                            window=0, softcap=0.0):
     """Multi-token attention of a prompt *chunk* against a KV cache.
@@ -340,6 +344,7 @@ def chunk_decode_attention(q, k_cache, v_cache, cache_pos, q_pos, *,
     return o.reshape(B, S, H, D)
 
 
+@scopes.scoped(scopes.ATTN_CORE)
 def decode_attention(q, k_cache, v_cache, cache_pos, *, window=0,
                      softcap=0.0):
     """q (B,1,H,D); caches (B,W,K,D); cache_pos (B,W) absolute positions of
@@ -363,6 +368,7 @@ def decode_attention(q, k_cache, v_cache, cache_pos, *, window=0,
     return o.reshape(B, 1, H, D)
 
 
+@scopes.scoped(scopes.ATTN_CORE)
 def sharded_decode(q, k_new, v_new, cache, positions, *, mesh, dp_axes,
                    tp_axis, window=0, softcap=0.0):
     """Flash-decode under shard_map: batch over dp, cache LENGTH over tp.
@@ -454,6 +460,7 @@ def sharded_decode(q, k_new, v_new, cache, positions, *, mesh, dp_axes,
     return out, {"k": ck, "v": cv, "pos": cp}
 
 
+@scopes.scoped(scopes.ATTN_CORE)
 def sharded_flash(q, k, v, *, mesh, dp_axes, tp_axis, causal=True,
                   window=0, softcap=0.0, q_block=512, kv_block=512):
     """Flash attention under an explicit ``shard_map``: batch over the dp
@@ -520,6 +527,7 @@ def sharded_flash(q, k, v, *, mesh, dp_axes, tp_axis, causal=True,
 # ---------------------------------------------------------------------------
 # block-level apply (projections + path dispatch + cache management)
 # ---------------------------------------------------------------------------
+@scopes.scoped(scopes.ATTN_PROJ)
 def project_qkv(params, x, cfg: ModelConfig, positions, compute_dtype):
     cd = compute_dtype
     x = x.astype(cd)
@@ -539,6 +547,13 @@ def project_qkv(params, x, cfg: ModelConfig, positions, compute_dtype):
         k = layers.apply_rope(k, positions, fraction=cfg.rope_fraction,
                               theta=cfg.rope_theta)
     return q, k, v
+
+
+@scopes.scoped(scopes.ATTN_PROJ)
+def project_out(params, o, compute_dtype):
+    """The heads' outputs (B, S, H, D) back to (B, S, d_model)."""
+    cd = compute_dtype
+    return jnp.einsum("bshe,hed->bsd", o.astype(cd), params["wo"].astype(cd))
 
 
 def apply_attention(params, x, cfg: ModelConfig, *, local: bool,
@@ -593,8 +608,7 @@ def apply_attention(params, x, cfg: ModelConfig, *, local: bool,
                 o = chunk_decode_attention(q, k_cache, v_cache, pos_cache,
                                            positions, window=window,
                                            softcap=cfg.logit_softcap)
-            out = jnp.einsum("bshe,hed->bsd", o.astype(cd),
-                             params["wo"].astype(cd))
+            out = project_out(params, o, cd)
             return out, {"k": k_cache, "v": v_cache, "pos": pos_cache}
         # ---- decode: single new token at absolute position `positions` ----
         q, k_new, v_new = project_qkv(params, x, cfg, positions, cd)
@@ -605,8 +619,7 @@ def apply_attention(params, x, cfg: ModelConfig, *, local: bool,
                                  softcap=cfg.logit_softcap)
             if res is not None:
                 o, new_cache = res
-                out = jnp.einsum("bshe,hed->bsd", o.astype(cd),
-                                 params["wo"].astype(cd))
+                out = project_out(params, o, cd)
                 return out, new_cache
         W = cache["k"].shape[1]
         slot = (positions[:, 0] % W) if window > 0 else positions[:, 0]
@@ -616,8 +629,7 @@ def apply_attention(params, x, cfg: ModelConfig, *, local: bool,
         pos_cache = cache["pos"].at[bidx, slot].set(positions[:, 0])
         o = decode_attention(q, k_cache, v_cache, pos_cache, window=window,
                              softcap=cfg.logit_softcap)
-        out = jnp.einsum("bshe,hed->bsd", o.astype(cd),
-                         params["wo"].astype(cd))
+        out = project_out(params, o, cd)
         return out, {"k": k_cache, "v": v_cache, "pos": pos_cache}
 
     q, k, v = project_qkv(params, x, cfg, positions, cd)
@@ -641,7 +653,7 @@ def apply_attention(params, x, cfg: ModelConfig, *, local: bool,
                                 q_block=blocks[0], kv_block=blocks[1],
                                 softcap=cfg.logit_softcap,
                                 batch_axes=batch_axes, head_axis=head_axis)
-    out = jnp.einsum("bshe,hed->bsd", o.astype(cd), params["wo"].astype(cd))
+    out = project_out(params, o, cd)
 
     new_cache = None
     if cache == "init":

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import scopes
+
 
 def manual_axes() -> frozenset:
     """Mesh axes already manual in the enclosing ``shard_map`` (empty
@@ -51,6 +53,7 @@ def init_norm(kind: str, d: int, dtype=jnp.float32):
     raise ValueError(kind)
 
 
+@scopes.scoped(scopes.NORM)
 def apply_norm(params, x, kind: str, eps: float = 1e-5):
     dt = x.dtype
     xf = x.astype(jnp.float32)
@@ -115,6 +118,7 @@ def init_mlp(key, d_model: int, d_ff: int, act: str, dtype=jnp.float32):
     }
 
 
+@scopes.scoped(scopes.MLP)
 def apply_mlp(params, x, act: str, compute_dtype=jnp.bfloat16):
     x = x.astype(compute_dtype)
     wi = params["wi"].astype(compute_dtype)

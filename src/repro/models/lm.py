@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import layers, transformer
+from repro.models import layers, scopes, transformer
 from repro.models.transformer import RunCtx
 
 
@@ -36,6 +36,7 @@ def init_lm(key, cfg: ModelConfig, dtype=jnp.float32):
     return p
 
 
+@scopes.scoped(scopes.EMBED)
 def embed_inputs(params, inputs, cfg: ModelConfig, ctx: RunCtx, positions):
     cd = ctx.compute_dtype
     if cfg.input_mode == "embeddings":
@@ -81,12 +82,13 @@ def lm_loss(params, batch, cfg: ModelConfig, ctx: RunCtx, *,
                              return_hidden=True)
     table = head_table(params, cfg)
     mask = batch.get("mask")
-    if xent_chunk and hidden.shape[1] % xent_chunk == 0:
-        xent = layers.chunked_softmax_xent(
-            hidden, table, batch["labels"], chunk=xent_chunk,
-            compute_dtype=ctx.compute_dtype, mask=mask)
-    else:
-        logits = layers.unembed(table, hidden, ctx.compute_dtype)
-        xent = layers.softmax_xent(logits, batch["labels"], mask)
+    with jax.named_scope(scopes.HEAD):
+        if xent_chunk and hidden.shape[1] % xent_chunk == 0:
+            xent = layers.chunked_softmax_xent(
+                hidden, table, batch["labels"], chunk=xent_chunk,
+                compute_dtype=ctx.compute_dtype, mask=mask)
+        else:
+            logits = layers.unembed(table, hidden, ctx.compute_dtype)
+            xent = layers.softmax_xent(logits, batch["labels"], mask)
     loss = xent + aux_weight * aux
     return loss, {"loss": loss, "xent": xent, "aux": aux}

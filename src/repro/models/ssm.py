@@ -28,12 +28,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import layers
+from repro.models import layers, scopes
 
 
 # ---------------------------------------------------------------------------
 # chunked SSD core
 # ---------------------------------------------------------------------------
+@scopes.scoped(scopes.SSD)
 def ssd_chunked(x, dt, A, Bm, Cm, *, chunk: int, h0=None):
     """Returns (y (B,S,H,P), h_final (B,H,N,P)). All math fp32."""
     Bsz, S, H, P = x.shape
@@ -142,6 +143,7 @@ def ssd_sharded(x, dt, A, Bm, Cm, *, chunk: int, mesh, dp_axes, tp_axis):
     )(x, dt, A, Bm, Cm)
 
 
+@scopes.scoped(scopes.SSD)
 def ssd_decode_step(x, dt, A, Bm, Cm, h):
     """One token. x (B,H,P); dt (B,H); B/C (B,G,N); h (B,H,N,P)."""
     H, G = x.shape[1], Bm.shape[1]

@@ -40,7 +40,7 @@ from repro.cluster.telemetry import ServingStats
 from repro.configs.base import ATTN, ModelConfig, PolicyConfig, ShapeConfig
 from repro.core import policy as pol
 from repro.kernels.registry import bucket_pow2
-from repro.models import lm, transformer
+from repro.models import lm, scopes, transformer
 from repro.models.transformer import RunCtx
 from repro.serve import kvcache
 from repro.serve.scheduler import (DECODE, PREFILL, RequestScheduler,
@@ -407,9 +407,11 @@ class AsyncServeEngine:
             self.pool.page_size, self.segs, self.pool.trash)
         h = hidden[jnp.arange(toks.shape[0]), last_idx]
         table_w = lm.head_table(params, self.cfg)
-        logits = (h.astype(self.ctx.compute_dtype)
-                  @ table_w.astype(self.ctx.compute_dtype).T)
-        return jnp.argmax(logits, -1).astype(jnp.int32), logits, pages
+        with jax.named_scope(scopes.HEAD):
+            logits = (h.astype(self.ctx.compute_dtype)
+                      @ table_w.astype(self.ctx.compute_dtype).T)
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        return nxt, logits, pages
 
     def _table_width(self, reqs: List[ServeRequest]) -> int:
         """Bucketed block-table width for this batch (shared jit key)."""
@@ -417,14 +419,72 @@ class AsyncServeEngine:
         cap = self.pool.pages_for(self.max_seq)
         return min(bucket_pow2(need, floor=1), cap)
 
-    def _run_paged(self, reqs: List[ServeRequest], toks, positions, valid,
-                   last_idx):
-        """Returns (next tokens, last-position logits) for the live rows
-        (padding rows stripped)."""
+    def _paged_pass(self, plan: List[Tuple[ServeRequest, int]],
+                    W: int) -> int:
+        """ONE jitted paged step over ``plan``'s rows, each ``(request,
+        tokens this step)``: a decode row carries its last token, a
+        prefill row its next chunk, each padded to width ``W``.  Padded
+        columns carry positions AFTER the row's valid tokens (causal
+        masking excludes them) and their K/V scatter lands on the scratch
+        page, so each row's logits do not depend on what it is batched
+        with.  Returns the tokens processed."""
+        reqs = [r for r, _ in plan]
+        with scopes.serve_span("inputs"):
+            toks, poss, vals, last = [], [], [], []
+            for r, n in plan:
+                if r.state == DECODE:
+                    p0 = r.prompt_len + len(r.out) - 1
+                    toks.append([r.out[-1]] + [0] * (W - 1))
+                    poss.append([p0 + i for i in range(W)])
+                    vals.append([True] + [False] * (W - 1))
+                    last.append(0)
+                else:
+                    row = [int(t) for t in
+                           r.prompt[r.prefilled:r.prefilled + n]]
+                    toks.append(row + [0] * (W - n))
+                    poss.append(list(range(r.prefilled, r.prefilled + W)))
+                    vals.append([i < n for i in range(W)])
+                    last.append(n - 1)
+            args = self._paged_args(reqs, jnp.asarray(toks, jnp.int32),
+                                    jnp.asarray(poss, jnp.int32),
+                                    jnp.asarray(vals, bool),
+                                    jnp.asarray(last, jnp.int32))
+        with scopes.serve_span("device"):
+            nxt, _, self.pool.pages = self._paged_step(
+                self.params, self.pool.pages, *args)
+            jax.block_until_ready(nxt)
+        with scopes.serve_span("tokens"):
+            now = self.now()    # token timestamps see the finished step
+            done_tokens = 0
+            for i, (r, n) in enumerate(plan):
+                done_tokens += n
+                if r.state == DECODE:
+                    r.table.n_tokens += 1
+                    if self.sched.note_token(r, int(nxt[i]), now):
+                        self._finish(r, now)
+                    continue
+                r.table.n_tokens = r.prefilled + n
+                self.sched.note_prefilled(r, n, now)
+                if r.state == DECODE:
+                    # prompt complete: register its full pages now — they
+                    # are immutable from this point, so concurrent
+                    # shared-prefix requests can hit them while this one
+                    # is still decoding — and the chunk's last hidden IS
+                    # the first generated token (no separate "first
+                    # decode" step)
+                    self.pool.register_prefix(r.prompt, r.table)
+                    if self.sched.note_token(r, int(nxt[i]), now):
+                        self._finish(r, now)
+        return done_tokens
+
+    def _paged_args(self, reqs: List[ServeRequest], toks, positions, valid,
+                    last_idx):
+        """The step's arguments after the pool: block tables at the
+        bucketed width, every row array padded to the pow2 batch bucket
+        (padding rows write to the scratch page)."""
         P = self._table_width(reqs)
         B = len(reqs)
-        Bpad = min(bucket_pow2(B, floor=1), self.n_slots)
-        pad = Bpad - B
+        pad = min(bucket_pow2(B, floor=1), self.n_slots) - B
         tables = jnp.stack(
             [self.pool.padded_table(r.table, P) for r in reqs]
             + [jnp.full((P,), self.pool.trash, jnp.int32)] * pad)
@@ -435,99 +495,7 @@ class AsyncServeEngine:
             valid = jnp.concatenate(
                 [valid, jnp.zeros((pad, valid.shape[1]), bool)])
             last_idx = jnp.concatenate([last_idx, zcol[:, 0]])
-        nxt, logits, self.pool.pages = self._paged_step(
-            self.params, self.pool.pages, tables, toks, positions, valid,
-            last_idx)
-        return nxt, logits[:B]
-
-    def _paged_prefill_chunks(self, now: float) -> int:
-        work = self.sched.prefill_work()
-        if not work:
-            return 0
-        C = self.prefill_chunk
-        toks, poss, vals, last = [], [], [], []
-        for r in work:
-            n = self.sched.chunk_for(r)
-            row = [int(t) for t in r.prompt[r.prefilled:r.prefilled + n]]
-            row += [0] * (C - n)
-            toks.append(row)
-            poss.append(list(range(r.prefilled, r.prefilled + C)))
-            vals.append([i < n for i in range(C)])
-            last.append(n - 1)
-        nxt, _ = self._run_paged(
-            work, jnp.asarray(toks, jnp.int32), jnp.asarray(poss, jnp.int32),
-            jnp.asarray(vals, bool), jnp.asarray(last, jnp.int32))
-        jax.block_until_ready(nxt)
-        now = self.now()        # token timestamps see the finished step
-        done_tokens = 0
-        for i, r in enumerate(work):
-            n = self.sched.chunk_for(r)
-            done_tokens += n
-            r.table.n_tokens = r.prefilled + n
-            self.sched.note_prefilled(r, n, now)
-            if r.state == DECODE:
-                # prompt complete: register its full pages now — they are
-                # immutable from this point, so concurrent shared-prefix
-                # requests can hit them while this one is still decoding —
-                # and the chunk's last hidden IS the first generated token
-                # (no separate "first decode" step)
-                self.pool.register_prefix(r.prompt, r.table)
-                if self.sched.note_token(r, int(nxt[i]), now):
-                    self._finish(r, now)
-        return done_tokens
-
-    def _paged_fused(self, now: float) -> int:
-        """True continuous batching: ONE jitted step over a mixed batch
-        of decode rows (width-1) and prefill chunks, per the scheduler's
-        token-budget ``iteration_plan``.  Row width pads to 1 (pure
-        decode) or ``prefill_chunk`` (any prefill present) so the trace
-        count stays O(log n_slots) x 2; padded columns carry positions
-        AFTER the row's valid tokens (causal masking excludes them) and
-        their K/V scatter lands on the scratch page — each row's logits
-        are bit-identical to the unfused two-step path."""
-        plan = self.sched.iteration_plan()
-        if not plan:
-            return 0
-        pure_decode = all(r.state == DECODE for r, _ in plan)
-        W = 1 if pure_decode else self.prefill_chunk
-        toks, poss, vals, last = [], [], [], []
-        for r, n in plan:
-            if r.state == DECODE:
-                p0 = r.prompt_len + len(r.out) - 1
-                toks.append([r.out[-1]] + [0] * (W - 1))
-                poss.append([p0 + i for i in range(W)])
-                vals.append([True] + [False] * (W - 1))
-                last.append(0)
-            else:
-                row = [int(t) for t in r.prompt[r.prefilled:r.prefilled + n]]
-                toks.append(row + [0] * (W - n))
-                poss.append(list(range(r.prefilled, r.prefilled + W)))
-                vals.append([i < n for i in range(W)])
-                last.append(n - 1)
-        nxt, _ = self._run_paged(
-            [r for r, _ in plan], jnp.asarray(toks, jnp.int32),
-            jnp.asarray(poss, jnp.int32), jnp.asarray(vals, bool),
-            jnp.asarray(last, jnp.int32))
-        jax.block_until_ready(nxt)
-        now = self.now()        # token timestamps see the finished step
-        done_tokens = 0
-        for i, (r, n) in enumerate(plan):
-            done_tokens += n
-            if r.state == DECODE:
-                r.table.n_tokens += 1
-                if self.sched.note_token(r, int(nxt[i]), now):
-                    self._finish(r, now)
-                continue
-            r.table.n_tokens = r.prefilled + n
-            self.sched.note_prefilled(r, n, now)
-            if r.state == DECODE:
-                # prompt complete: register its (now immutable) full
-                # pages and take the chunk's last hidden as the first
-                # generated token, exactly like the unfused chunk path
-                self.pool.register_prefix(r.prompt, r.table)
-                if self.sched.note_token(r, int(nxt[i]), now):
-                    self._finish(r, now)
-        return done_tokens
+        return tables, toks, positions, valid, last_idx
 
     def warmup(self, max_tokens: Optional[int] = None) -> float:
         """Pre-compile the paged step's jit traces: every pow2 batch
@@ -559,24 +527,6 @@ class AsyncServeEngine:
         dt = time.perf_counter() - t0
         self.compile_s += dt
         return dt
-
-    def _paged_decode(self, now: float) -> int:
-        work = [r for r in self.sched.decode_work() if r.out]
-        if not work:
-            return 0
-        toks = jnp.asarray([[r.out[-1]] for r in work], jnp.int32)
-        pos = jnp.asarray(
-            [[r.prompt_len + len(r.out) - 1] for r in work], jnp.int32)
-        valid = jnp.ones((len(work), 1), bool)
-        last = jnp.zeros((len(work),), jnp.int32)
-        nxt, _ = self._run_paged(work, toks, pos, valid, last)
-        jax.block_until_ready(nxt)
-        now = self.now()        # token timestamps see the finished step
-        for i, r in enumerate(work):
-            r.table.n_tokens += 1
-            if self.sched.note_token(r, int(nxt[i]), now):
-                self._finish(r, now)
-        return len(work)
 
     # ------------------------------------------------------- dense stepping --
     def _dense_prefill(self, now: float) -> int:
@@ -628,14 +578,29 @@ class AsyncServeEngine:
         eng.sched.all_done()``."""
         now = self.now()
         self._iters += 1
-        self._expire_timeouts(now)
-        self.sched.admit(now, self._try_open)
+        with scopes.serve_span("admit"):
+            self._expire_timeouts(now)
+            self.sched.admit(now, self._try_open)
         if self.mode == "paged":
             if self.fused:
-                n = self._paged_fused(now)
+                # true continuous batching: decode rows and prefill chunks
+                # in ONE step, per the token-budget plan; rows pad to 1
+                # (pure decode) or the chunk, so the trace count stays
+                # O(log n_slots) x 2
+                with scopes.serve_span("plan"):
+                    plan = self.sched.iteration_plan()
+                    pure = all(r.state == DECODE for r, _ in plan)
+                n = self._paged_pass(
+                    plan, 1 if pure else self.prefill_chunk) if plan else 0
             else:
-                n = self._paged_prefill_chunks(now)
-                n += self._paged_decode(now)
+                # two steps: the batched prefill chunks, then every decode
+                with scopes.serve_span("plan"):
+                    plan = [(r, self.sched.chunk_for(r))
+                            for r in self.sched.prefill_work()]
+                n = self._paged_pass(plan, self.prefill_chunk) if plan else 0
+                with scopes.serve_span("plan"):
+                    plan = [(r, 1) for r in self.sched.decode_work() if r.out]
+                n += self._paged_pass(plan, 1) if plan else 0
             self._util_sum += self.pool.utilization()
         else:
             n = self._dense_prefill(now)

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ATTN, ModelConfig
-from repro.models import transformer
+from repro.models import scopes, transformer
 
 
 class PageError(RuntimeError):
@@ -69,6 +69,7 @@ def _is_pos_leaf(path) -> bool:
 # ---------------------------------------------------------------------------
 # device-side views (pure functions; the engine jits them with the model)
 # ---------------------------------------------------------------------------
+@scopes.scoped(scopes.KV_GATHER)
 def gather_dense(pages, tables, segs):
     """Materialize the dense per-sequence cache view from the pool.
 
@@ -86,6 +87,7 @@ def gather_dense(pages, tables, segs):
     return jax.tree_util.tree_map_with_path(g, pages)
 
 
+@scopes.scoped(scopes.KV_SCATTER)
 def scatter_tokens(pages, dense, tables, positions, valid, page_size, segs,
                    trash: int):
     """Write the tokens at ``positions`` (B, S) from the dense view back

@@ -27,7 +27,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, PolicyConfig, ShapeConfig
 from repro.core import hierarchy, policy as pol
-from repro.models import lm
+from repro.models import lm, scopes
 from repro.models.transformer import ParallelCtx, RunCtx
 from repro.optim import adamw, schedule
 
@@ -205,6 +205,7 @@ def make_train_step(cfg: ModelConfig, policy: PolicyConfig,
         "pod" in mesh_axes and mesh_axes["pod"] > 1
         and (policy.grad_compression == "int8_ef"))
 
+    @scopes.scoped(scopes.OPTIMIZER)
     def optimizer_update(state: TrainState, grads, metrics, loss):
         lr = None
         if schedcfg is not None:

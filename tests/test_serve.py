@@ -372,7 +372,7 @@ def test_fused_mixed_batch_rows_bit_exact(small_lm):
     plan = eng.sched.iteration_plan()
     assert [(r.rid, n) for r, n in plan] == [(0, 1), (1, C)]
 
-    # build the fused rows exactly as _paged_fused does (W = chunk)
+    # build the fused rows exactly as _paged_pass does (W = chunk)
     p0 = r0.prompt_len + len(r0.out) - 1
     toks = [[r0.out[-1]] + [0] * (C - 1),
             [int(t) for t in r1.prompt[:C]]]
