@@ -9,6 +9,10 @@ Paths:
                      the dry-run implementation.
   * ``decode``     — single-token attention over a (possibly ring-buffered)
                      KV cache.
+  * the Pallas kernels (``kernels.flash_attention_bwd``) — on the TPU, for
+                     unsharded, unwindowed, unmasked calls whose lengths
+                     the kernels' tiles divide (``kernel_takes``): forward
+                     and backward on chip, no score tile saved to HBM.
 
 All paths support GQA (H = K * G query groups), causal masking, and sliding
 windows. Shapes: q (B, S, H, D); k/v (B, T, Kh, D).
@@ -22,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import flash_attention_bwd as fab
 from repro.models import layers, scopes
 
 
@@ -524,6 +529,35 @@ def sharded_flash(q, k, v, *, mesh, dp_axes, tp_axis, causal=True,
     return out[:, :, :H]
 
 
+def on_tpu() -> bool:
+    """Whether this trace compiles for the TPU, the one backend that
+    compiles the Pallas kernels; read at trace time, so the untaken path
+    leaves nothing (no residuals) in the program."""
+    return jax.default_backend() == "tpu"
+
+
+def kernel_takes(q, k, window: int, kv_mask) -> bool:
+    """Whether an unsharded, uncached call runs the fused Pallas kernels:
+    on the TPU, full (not windowed) attention, no key mask, and lengths
+    that the kernels' tiles divide.  Every other call keeps the XLA
+    paths."""
+    if not on_tpu() or window > 0 or kv_mask is not None:
+        return False
+    bq, bk = fab.kernel_blocks(q.shape[2] // k.shape[2], q.shape[3])
+    return q.shape[1] % bq == 0 and k.shape[1] % bk == 0
+
+
+@scopes.scoped(scopes.ATTN_CORE)
+def flash_kernel_attention(q, k, v, *, causal=True, softcap=0.0):
+    """The fused Pallas flash attention (``kernels.flash_attention_bwd``):
+    forward and backward on chip, recomputing score tiles in the backward
+    from the saved row statistics instead of keeping them in HBM."""
+    from repro.kernels.ops import default_interpret
+    bq, bk = fab.kernel_blocks(q.shape[2] // k.shape[2], q.shape[3])
+    return fab.flash_attention_vjp(q, k, v, causal, 0, softcap, bq, bk,
+                                   default_interpret())
+
+
 # ---------------------------------------------------------------------------
 # block-level apply (projections + path dispatch + cache management)
 # ---------------------------------------------------------------------------
@@ -642,6 +676,9 @@ def apply_attention(params, x, cfg: ModelConfig, *, local: bool,
                           tp_axis=tp_axis, causal=cfg.causal,
                           window=window, softcap=cfg.logit_softcap,
                           q_block=blocks[0], kv_block=blocks[1])
+    elif kernel_takes(q, k, window, kv_mask):
+        o = flash_kernel_attention(q, k, v, causal=cfg.causal,
+                                   softcap=cfg.logit_softcap)
     elif window > 0:
         # sliding-span O(S·w) flash path for windowed blocks
         o = local_flash_xla(q, k, v, window=window, causal=cfg.causal,

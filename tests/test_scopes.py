@@ -102,6 +102,41 @@ def test_attn_core_labels_dots_in_every_pass():
     assert all("/while/body/" in n for n in names)     # inside the scan
 
 
+def test_attn_core_labels_flash_kernels_in_every_pass(monkeypatch):
+    """On the TPU the train step's attention is the Pallas flash kernels:
+    the forward kernel in the forward pass and again in the remat
+    recompute, and both backward kernels, each under ``attn_core``.  The
+    step is lowered for the TPU (no chip needed) with the backend
+    predicates turned to the TPU's answers; each custom call's location
+    is the ``op_name`` it compiles to."""
+    from repro.kernels import ops
+    from repro.models import attention
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    cfg = reduced(get_config("qwen2-0.5b"))
+    seq = attention.fab.kernel_blocks(
+        cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)[1]
+    policy = PolicyConfig(compute_dtype="bfloat16", remat="block",
+                          attn_impl="xla")
+    opt = adamw.AdamWConfig()
+    step = trainer.make_train_step(cfg, policy, opt,
+                                   shape=ShapeConfig("t", seq, 2, "train"))
+    state = jax.eval_shape(lambda k: trainer.init_state(k, cfg, policy, opt),
+                           jax.random.PRNGKey(0))
+    ids = jax.ShapeDtypeStruct((2, seq), jnp.int32)
+    text = jax.jit(step).trace(state, {"inputs": ids, "labels": ids}).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    names = [locs[n] for n in re.findall(
+        r'custom_call @tpu_custom_call\(.*loc\((#loc\d+)\)$', text, re.M)]
+    assert all("attn_core" in n.split("/") for n in names), names
+    kernels = sorted(n.split("/")[-2] for n in names)
+    assert kernels == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+                       "flash_attention_fwd", "flash_attention_fwd"], names
+    remat = [n for n in names if "rematted_computation" in n]
+    assert len(remat) == 1 and "flash_attention_fwd" in remat[0], names
+
+
 # ---------------------------------------------------------------------------
 # host spans of the serving loop
 # ---------------------------------------------------------------------------

@@ -11,6 +11,7 @@ every test worker imports this file.
 import functools
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,7 +73,7 @@ def _total_bytes(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-def test_full_width_train_step_fits_one_chip(one_chip, smoke):
+def _smoke_train_step(one_chip, smoke):
     from repro.configs.base import ShapeConfig
     from repro.launch import specs, train
     from repro.train import trainer
@@ -81,12 +82,63 @@ def test_full_width_train_step_fits_one_chip(one_chip, smoke):
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
     step = trainer.make_train_step(cfg, policy, optcfg, schedcfg,
                                    shape=shape)
-    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+    return jax.jit(step, donate_argnums=(0,)).lower(
         _on(specs.state_structs(cfg, policy, optcfg), one_chip),
         _on(specs.batch_structs(cfg, shape), one_chip)).compile()
-    m = compiled.memory_analysis()
-    assert m.alias_size_in_bytes > 0          # the donated state is reused
-    assert _total_bytes(compiled) < HBM_BYTES, m
+
+
+@pytest.fixture(scope="module")
+def xla_train_step(one_chip, smoke):
+    """The smoke's train step as a CPU-driven trace builds it: attention
+    through the XLA tile scans."""
+    return _smoke_train_step(one_chip, smoke)
+
+
+@pytest.fixture(scope="module")
+def kernel_train_step(one_chip, smoke):
+    """The same step as the chip builds it: attention through the Pallas
+    flash kernels, the backend predicates turned to the TPU's answers."""
+    from repro.kernels import ops
+    from repro.models import attention
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(attention, "on_tpu", lambda: True)
+        mp.setattr(ops, "default_interpret", lambda: False)
+        return _smoke_train_step(one_chip, smoke)
+
+
+def test_full_width_train_step_fits_one_chip(xla_train_step,
+                                             kernel_train_step):
+    for compiled in (xla_train_step, kernel_train_step):
+        m = compiled.memory_analysis()
+        assert m.alias_size_in_bytes > 0      # the donated state is reused
+        assert _total_bytes(compiled) < HBM_BYTES, m
+
+
+# a score tile saved for the backward: f32[..., 512, 512] written into the
+# scan's stacked residuals
+TILE_RESIDUAL = re.compile(
+    r"= f32\[[\d,]*512,512\][^=]*(dynamic-update-slice|fusion)\(")
+
+
+def test_train_step_runs_attention_in_the_flash_kernels(xla_train_step,
+                                                        kernel_train_step):
+    """On the chip the train step's attention is the three Pallas kernels
+    (forward, in the forward and the remat recompute, and both backward
+    kernels), every one under ``attn_core``; the f32 score tiles the XLA
+    scans save go, and the step needs less memory."""
+    text = kernel_train_step.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    names = [re.search(r"%(\S+) = ", ln).group(1) for ln in calls]
+    count = lambda kernel: sum(kernel in n for n in names)
+    assert count("flash_attention_fwd") == 2, names     # forward, remat
+    assert count("flash_attention_bwd_dkv") == 1, names
+    assert count("flash_attention_bwd_dq") == 1, names
+    assert all('/attn_core/' in re.search(r'op_name="([^"]*)"', ln).group(1)
+               for ln in calls)
+    assert TILE_RESIDUAL.search(xla_train_step.as_text())
+    assert not TILE_RESIDUAL.search(text)
+    assert _total_bytes(kernel_train_step) < _total_bytes(xla_train_step)
 
 
 @pytest.mark.parametrize("width", ["decode", "prefill_chunk"])
@@ -127,11 +179,13 @@ def _flash_fwd():
 
 
 def _flash_vjp():
-    from repro.kernels.flash_attention_bwd import flash_attention_vjp
+    from repro.kernels.flash_attention_bwd import (flash_attention_vjp,
+                                                   kernel_blocks)
+    bq, bk = kernel_blocks(Q[2] // KV[2], Q[3])
 
     def grads(q, k, v):
         return jax.grad(lambda *a: flash_attention_vjp(
-            *a, True, 0, 0.0, 256, 256, False).astype(F32).sum(),
+            *a, True, 0, 0.0, bq, bk, False).astype(F32).sum(),
             argnums=(0, 1, 2))(q, k, v)
     return grads, ((Q, BF16), (KV, BF16), (KV, BF16))
 
@@ -164,9 +218,7 @@ def _refused(reason, raises=Exception):
 
 @pytest.mark.parametrize("kernel", [
     pytest.param(_flash_fwd, id="flash_attention"),
-    pytest.param(_flash_vjp, id="flash_attention_vjp", marks=_refused(
-        "Mosaic failed to compile TPU kernel: Invalid input layout "
-        "(tpu.reshape of the row statistics)")),
+    pytest.param(_flash_vjp, id="flash_attention_vjp"),
     pytest.param(_paged_decode, id="paged_decode_attention", marks=_refused(
         "the Pallas TPU lowering requires the last two dims of a block "
         "shape divisible by 8 and 128 or equal to the array's: page "
