@@ -1,6 +1,7 @@
 """Compile rehearsal for TPU v5e: the main path's programs at the widths
-``chip_smoke.py`` runs, and the Pallas kernels, compiled for a described
-(not attached) ``v5e:2x2`` chip.  Nothing runs; this catches what the
+``chip_smoke.py`` runs, mamba2-780m's ZeRO-3 train step over four chips
+as its benchmark cell runs it, and the Pallas kernels, compiled for a
+described (not attached) ``v5e:2x2`` chip.  Nothing runs; this catches what the
 chip's compiler refuses (tiling, layouts, unlowered primitives, memory)
 before chip time is spent.
 
@@ -165,6 +166,67 @@ def test_full_width_paged_serve_step_compiles(one_chip, smoke, width):
         jax.ShapeDtypeStruct((B, P), I32, sharding=one_chip), *rows,
         jax.ShapeDtypeStruct((B,), I32, sharding=one_chip)).compile()
     assert _total_bytes(compiled) < HBM_BYTES
+
+
+def _mamba2_zero3_step(devices):
+    """mamba2-780m's train step at the widths and policy of its benchmark
+    cell (global batch 16 x 2048, ZeRO-3, bf16 compute, f32 AdamW, block
+    remat, the state donated), jitted over a (data, model) = (n, 1) mesh
+    of ``devices`` as ``trainer.jit_train_step`` does."""
+    from repro.configs import get_config
+    from repro.configs.base import PolicyConfig, ShapeConfig
+    from repro.launch import specs
+    from repro.launch.mesh import make_mesh
+    from repro.optim import adamw
+    from repro.train import trainer
+    cfg = get_config("mamba2-780m")
+    policy = PolicyConfig(compute_dtype="bfloat16", param_dtype="float32",
+                          remat="block", zero_stage=3, attn_impl="xla")
+    optcfg = adamw.AdamWConfig(lr=3e-4, b1=0.9, b2=0.95, eps=1e-8,
+                               weight_decay=0.1, grad_clip=1.0)
+    shape = ShapeConfig("bench", 2048, 16, "train")
+    mesh = make_mesh((len(devices), 1), ("data", "model"),
+                     devices=devices)
+    state = specs.state_structs(cfg, policy, optcfg)
+    batch = specs.batch_structs(cfg, shape)
+    step = trainer.jit_train_step(
+        trainer.make_train_step(cfg, policy, optcfg, None, mesh=mesh,
+                                shape=shape),
+        state, cfg, policy, mesh, batch)
+    return step.lower(state, batch).compile()
+
+
+# per chip, what XLA may allocate of a v5e's 16 GiB
+V5E_USABLE = 15.75 * 2**30
+
+
+@pytest.fixture(scope="module")
+def mamba2_zero3_four_chips(topo, one_chip):
+    return _mamba2_zero3_step(topo.devices)
+
+
+def test_mamba2_zero3_train_step_fits_four_chips(mamba2_zero3_four_chips):
+    """The mamba2 cell's step, state and all, fits each of four chips."""
+    m = mamba2_zero3_four_chips.memory_analysis()
+    assert m.alias_size_in_bytes > 0      # the donated state is reused
+    assert _total_bytes(mamba2_zero3_four_chips) <= V5E_USABLE, m
+
+
+def test_mamba2_ssd_dots_are_scoped_in_every_pass(mamba2_zero3_four_chips):
+    """Every matrix product of the chunk scan (a loop inside the layer
+    scan) is under ``ssd``: in the forward pass, the block-remat recompute
+    and the backward pass, four products each way at least."""
+    text = mamba2_zero3_four_chips.as_text()
+    dots = [re.search(r'op_name="([^"]*)"', ln).group(1)
+            for ln in text.splitlines()
+            if re.search(r" (dot|convolution)\(", ln) and "op_name=" in ln]
+    scan = [n for n in dots if n.count("while/body") >= 2]
+    assert scan and all("/ssd/" in n for n in scan), scan
+    fwd = [n for n in scan if not n.startswith("jit(train_step)/transpose")]
+    remat = [n for n in scan if "rematted_computation" in n]
+    bwd = [n for n in scan if n.startswith("jit(train_step)/transpose")
+           and "rematted_computation" not in n]
+    assert len(fwd) >= 4 and len(remat) >= 4 and len(bwd) >= 8, scan
 
 
 # qwen2-0.5b attention widths: 14 query heads over 2 kv heads of 64
