@@ -36,7 +36,14 @@ from repro.models import layers, scopes
 # ---------------------------------------------------------------------------
 @scopes.scoped(scopes.SSD)
 def ssd_chunked(x, dt, A, Bm, Cm, *, chunk: int, h0=None):
-    """Returns (y (B,S,H,P), h_final (B,H,N,P)). All math fp32."""
+    """Returns (y (B,S,H,P), h_final (B,H,N,P)). All math fp32.
+
+    Under differentiation the chunk scan saves, per chunk, only its
+    inputs (the chunk's x, dt, B, C) and the state ``h`` carried into
+    it: no (B, L, L, H) tile is stacked over chunks; the backward
+    recomputes each chunk's tiles where it consumes them.  A call that
+    is not differentiated compiles to the same program as without the
+    checkpoint."""
     Bsz, S, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
     hpg = H // G
@@ -96,6 +103,8 @@ def ssd_chunked(x, dt, A, Bm, Cm, *, chunk: int, h0=None):
         h_next = jnp.exp(acum[:, -1, :])[..., None, None] * h + contrib
         return h_next, y_intra + y_inter
 
+    step = jax.checkpoint(step, prevent_cse=False,
+                          policy=jax.checkpoint_policies.nothing_saveable)
     h_final, ys = jax.lax.scan(step, h0, (xs, dts, Bs, Cs))
     y = ys.swapaxes(0, 1).reshape(Bsz, S, H, P)
     return y[:, :S_orig], h_final

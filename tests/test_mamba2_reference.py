@@ -90,6 +90,56 @@ def test_ssd_sharded_and_chunked_match_the_reference():
         assert r["state"] < 1e-6, (g, r)
 
 
+@pytest.mark.parametrize("groups", [1, 2])
+def test_ssd_chunked_gradients_match_the_reference(groups):
+    """``jax.grad`` of a seeded scalar of ``ssd_chunked``'s (y, h_final)
+    with respect to x, dt, A, B and C against the same scalar of the
+    reference: y from ``ssd_minimal_discrete``, h_final as the listing's
+    ``decay_states`` over the whole sequence.  The chunk scan recomputes
+    each chunk's tiles in its backward; this holds the recompute to the
+    plain math.
+
+    Tolerance 1e-5 normwise per input, as for the forward: float32 at
+    highest precision on both sides, differing in the order of sums;
+    measured under 3e-6.  A backward that scales dt's gradient by 1.001
+    fails it."""
+    out = _run(f"""
+        from repro.models.ssm import ssd_chunked
+        m = c["mamba2_layer"]
+        Bsz, S, P, N, L = 8, 64, m["headdim"], m["d_state"], m["chunk_size"]
+        H, G = 4, {groups}
+        ks = jax.random.split(jax.random.PRNGKey(H * 10 + G), 7)
+        x = jax.random.normal(ks[0], (Bsz, S, H, P))
+        dt = jax.nn.softplus(jax.random.normal(ks[1], (Bsz, S, H)) - 2)
+        A = -jnp.exp(jax.random.uniform(ks[2], (H,), minval=0.0, maxval=2.7))
+        Bm = jax.random.normal(ks[3], (Bsz, S, G, N)) * 0.5
+        Cm = jax.random.normal(ks[4], (Bsz, S, G, N)) * 0.5
+        wy = jax.random.normal(ks[5], (Bsz, S, H, P))
+        wh = jax.random.normal(ks[6], (Bsz, H, N, P))
+
+        def program(x, dt, A, Bm, Cm):
+            y, h = ssd_chunked(x, dt, A, Bm, Cm, chunk=L)
+            return jnp.sum(y * wy) + jnp.sum(h * wh)
+
+        def reference(x, dt, A, Bm, Cm):
+            rep = lambda t: jnp.repeat(t, H // G, axis=2)
+            y = ref.ssd(x * dt[..., None], A * dt, rep(Bm), rep(Cm), L)
+            acum = jnp.cumsum(A * dt, axis=1)                  # (b, l, h)
+            h = jnp.einsum("blhn,blh,blhp->bhnp", rep(Bm),
+                           jnp.exp(acum[:, -1:] - acum) * dt, x,
+                           precision="highest")
+            return jnp.sum(y * wy) + jnp.sum(h * wh)
+
+        args = (x, dt, A, Bm, Cm)
+        got = jax.jit(jax.grad(program, argnums=range(5)))(*args)
+        want = jax.jit(jax.grad(reference, argnums=range(5)))(*args)
+        nrm = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+        print(json.dumps({{k: nrm(a, b) for k, a, b in
+                          zip(("x", "dt", "A", "B", "C"), got, want)}}))
+    """)
+    assert max(out.values()) < 1e-5, out
+
+
 # (d_model, vocab, the leaves ZeRO-3 splits over the four chips): at
 # cells.tiny's sizes every leaf is under the 64K elements below which the
 # policy replicates; twice as wide, the embedding and the projections

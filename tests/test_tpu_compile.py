@@ -11,6 +11,7 @@ every test worker imports this file.
 """
 import functools
 import importlib.util
+import math
 import os
 import re
 
@@ -168,6 +169,10 @@ def test_full_width_paged_serve_step_compiles(one_chip, smoke, width):
     assert _total_bytes(compiled) < HBM_BYTES
 
 
+# the mamba2 cell's global batch (rows x tokens), split over the data axis
+MAMBA2_BATCH, MAMBA2_SEQ = 16, 2048
+
+
 def _mamba2_zero3_step(devices):
     """mamba2-780m's train step at the widths and policy of its benchmark
     cell (global batch 16 x 2048, ZeRO-3, bf16 compute, f32 AdamW, block
@@ -184,7 +189,7 @@ def _mamba2_zero3_step(devices):
                           remat="block", zero_stage=3, attn_impl="xla")
     optcfg = adamw.AdamWConfig(lr=3e-4, b1=0.9, b2=0.95, eps=1e-8,
                                weight_decay=0.1, grad_clip=1.0)
-    shape = ShapeConfig("bench", 2048, 16, "train")
+    shape = ShapeConfig("bench", MAMBA2_SEQ, MAMBA2_BATCH, "train")
     mesh = make_mesh((len(devices), 1), ("data", "model"),
                      devices=devices)
     state = specs.state_structs(cfg, policy, optcfg)
@@ -227,6 +232,27 @@ def test_mamba2_ssd_dots_are_scoped_in_every_pass(mamba2_zero3_four_chips):
     bwd = [n for n in scan if n.startswith("jit(train_step)/transpose")
            and "rematted_computation" not in n]
     assert len(fwd) >= 4 and len(remat) >= 4 and len(bwd) >= 8, scan
+
+
+def test_mamba2_ssd_scan_stacks_no_tiles(topo, mamba2_zero3_four_chips):
+    """The chunk scan saves each chunk's inputs and carried state for the
+    backward, not its per-head L x L tiles: no array of the compiled step
+    holds (chunks, rows per chip, L, L, heads) elements, in whatever
+    layout, and the temporaries stay under 5 GiB per chip.  Stacking the
+    tiles (the scan body without its checkpoint) compiled to 77 lines of
+    such arrays and 7.46 GiB of temporaries; recomputing them, 3.83."""
+    from repro.configs import get_config
+    cfg = get_config("mamba2-780m")
+    s = cfg.ssm
+    heads = s.expand * cfg.d_model // s.head_dim
+    rows = MAMBA2_BATCH // len(topo.devices)
+    stack = (MAMBA2_SEQ // s.chunk) * rows * s.chunk * s.chunk * heads
+    stacked = [ln for ln in mamba2_zero3_four_chips.as_text().splitlines()
+               if any(math.prod(int(d) for d in dims.split(",")) == stack
+                      for dims in re.findall(r"\[(\d+(?:,\d+)+)\]", ln))]
+    assert not stacked, stacked[:3]
+    temp = mamba2_zero3_four_chips.memory_analysis().temp_size_in_bytes
+    assert temp <= 5 * 2**30, temp / 2**30
 
 
 # qwen2-0.5b attention widths: 14 query heads over 2 kv heads of 64
