@@ -42,10 +42,9 @@ def main() -> int:
     from benchmarks import (beyond_paper, chaos_bench, cluster_sim,
                             fabric_bench, fig10_utilization,
                             fig11_switch_overhead, fig12_traffic,
-                            fig15_storage, fig16_sw_opt, kernel_tune,
-                            recompose, recompose_bench, roofline,
-                            serve_bench, storage_bench, table2_models,
-                            table4_links)
+                            fig15_storage, fig16_sw_opt, recompose,
+                            recompose_bench, roofline, serve_bench,
+                            storage_bench, table2_models, table4_links)
     modules = {
         "table2": table2_models,
         "table4": table4_links,
@@ -61,7 +60,6 @@ def main() -> int:
         "chaos_bench": chaos_bench,
         "cluster_sim": cluster_sim,
         "fabric_bench": fabric_bench,
-        "kernel_tune": kernel_tune,
         "serve_bench": serve_bench,
         "storage_bench": storage_bench,
     }

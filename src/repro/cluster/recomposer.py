@@ -176,8 +176,7 @@ class Recomposer:
         dp, tp = job.dp_tp
         cand = recommend.calibrate_candidate(
             recommend._estimate(cfg, shape, dp // 2, tp),
-            cfg, job.arch, job.shape_name, shape,
-            self.scheduler.calibration)
+            job.arch, job.shape_name, self.scheduler.calibration)
         return cand if cand.feasible else None
 
     def _detach_pass(self, now: float) -> List[Job]:

@@ -194,11 +194,11 @@ class ServeJob(Job):
     A logical service runs ``n_replicas`` of these, each leasing its own
     pool slice through the ordinary admission path (the shape cell —
     ``decode_32k`` by default — prices the replica analytically, and
-    ``calibrate_candidate`` folds measured step times / tuned-kernel
-    speedups in, so token throughput is CalibratedCost-priced).  Unlike a
-    training job, a replica does not finish after ``steps`` — the
-    simulator completes it when its service's request trace drains;
-    ``steps`` only feeds the scheduler's EASY-backfill end-time estimate.
+    ``calibrate_candidate`` folds measured step times in, so token
+    throughput is CalibratedCost-priced).  Unlike a training job, a
+    replica does not finish after ``steps`` — the simulator completes it
+    when its service's request trace drains; ``steps`` only feeds the
+    scheduler's EASY-backfill end-time estimate.
     """
     service: str = ""                # logical service this replica serves
     replica: int = 0
@@ -484,12 +484,11 @@ class Scheduler:
                         recommend._estimate(cfg, shape, dp, tp,
                                             pods=job.n_pods, dcn_bw=dcn_bw,
                                             **topo_kw),
-                        cfg, job.arch, job.shape_name, shape,
-                        self.calibration)
+                        job.arch, job.shape_name, self.calibration)
                     for dp, tp in recommend.candidates(n // job.n_pods)]
         return [recommend.calibrate_candidate(
                     recommend._estimate(cfg, shape, dp, tp, **topo_kw),
-                    cfg, job.arch, job.shape_name, shape, self.calibration)
+                    job.arch, job.shape_name, self.calibration)
                 for dp, tp in recommend.candidates(n)]
 
     @staticmethod
@@ -928,8 +927,7 @@ class Scheduler:
                 cfg = get_config(job.arch)
                 new_plan = recommend.calibrate_candidate(
                     recommend._estimate(cfg, SHAPES[job.shape_name], dp, tp),
-                    cfg, job.arch, job.shape_name,
-                    SHAPES[job.shape_name], self.calibration)
+                    job.arch, job.shape_name, self.calibration)
                 if not new_plan.feasible:
                     # fits the pool by count but not by memory (e.g. the
                     # halved mesh can't hold the optimizer shards): the
@@ -1159,8 +1157,7 @@ class Scheduler:
         cfg = get_config(job.arch)
         new_plan = recommend.calibrate_candidate(
             recommend._estimate(cfg, SHAPES[job.shape_name], dp // 2, tp),
-            cfg, job.arch, job.shape_name, SHAPES[job.shape_name],
-            self.calibration)
+            job.arch, job.shape_name, self.calibration)
         if not new_plan.feasible:
             return 0
         if self.sync_progress is not None:
@@ -1259,8 +1256,7 @@ class Scheduler:
         cfg = get_config(job.arch)
         new_plan = recommend.calibrate_candidate(
             recommend._estimate(cfg, SHAPES[job.shape_name], dp // 2, tp),
-            cfg, job.arch, job.shape_name, SHAPES[job.shape_name],
-            self.calibration)
+            job.arch, job.shape_name, self.calibration)
         if not new_plan.feasible:
             return 0
         if self.sync_progress is not None:

@@ -228,8 +228,8 @@ class PolicyConfig:
 
     ``fsdp_axis`` shards parameters/optimizer state (ZeRO-3 analogue);
     ``dp_axes`` shard the batch; ``tp_axis`` (same physical axis as fsdp by
-    default on the 2D mesh) shards experts (EP) and, when enabled, FFN/head
-    dims (TP).  The paper's software-optimization ladder maps to:
+    default on the 2D mesh) shards experts (EP) and FFN/head dims (TP)
+    whenever its mesh axis is larger than 1.  The paper's software-optimization ladder maps to:
       DP        -> zero_stage=0, no fsdp (params replicated)
       DDP       -> zero_stage=0 with bucketed/overlapped grad psum
       mixed     -> compute_dtype=bf16
@@ -239,20 +239,14 @@ class PolicyConfig:
     fsdp_axes: Tuple[str, ...] = ("data",)
     tp_axis: Optional[str] = "model"
     ep: bool = True                 # experts over tp_axis
-    tp_ffn: bool = False            # Megatron-style FFN TP (perf option)
-    tp_attn_heads: bool = False     # head TP where divisible (perf option)
-    sp: bool = False                # shard sequence over tp_axis in mixers
     zero_stage: int = 3             # 0|1|3
     compute_dtype: str = "bfloat16"
     param_dtype: str = "float32"
-    accum_dtype: str = "float32"
     remat: str = "block"            # none | block | full
     grad_accum: int = 1
     hierarchical_allreduce: bool = True   # fast-domain first (multi-pod)
     grad_compression: str = "none"  # none | int8_ef
-    attn_impl: str = "xla"          # xla (chunked flash, CPU-lowerable) | pallas
-    scan_layers: bool = True
-    offload_activations: bool = False
+    attn_impl: str = "xla"          # xla (flash tiles) | full (oracle)
 
 
 DEFAULT_POLICY = PolicyConfig()

@@ -551,100 +551,26 @@ def roofline(report: CostReport, system: ComposedSystem,
 class CalibratedCost:
     """Measured-cost layer over the analytic model.
 
-    The analytic terms price every composition from first principles; this
-    layer folds *measurements* back in, in priority order:
-
-      1. ``cell_step_s`` — an exact measured step time for one
-         (arch, shape, mesh) cell (dry-run artifact, bench run, or the
-         cluster's own telemetry).  Replaces the whole step estimate.
-      2. ``kernel_speedup`` — measured default/best ratios from the
-         tuned-config registry (``kernels.autotune``).  Scales the
-         analytic *compute* term of every workload whose block pattern
-         uses that kernel family: tuned kernels execute the same FLOPs in
-         measurably less time, and the scheduler/simulator should price
-         that in.
-
-    Construct explicitly (tests, benches) or via ``from_registry()`` to
-    pull the speedups out of the active tuned-config registry.
+    The analytic terms price every composition from first principles;
+    ``cell_step_s`` folds measurements back in: an exact measured step
+    time for one (arch, shape, mesh) cell (dry-run artifact, bench run,
+    or the cluster's own telemetry) replaces that cell's whole step
+    estimate.  A cell with no measurement stays analytic.
     """
     cell_step_s: Dict[str, float] = dataclasses.field(default_factory=dict)
-    kernel_speedup: Dict[str, float] = dataclasses.field(
-        default_factory=dict)
 
     @staticmethod
     def cell_key(arch: str, shape_name: str, mesh_label: str) -> str:
         return f"{arch}|{shape_name}|{mesh_label}"
 
-    @classmethod
-    def from_registry(cls, registry=None) -> "CalibratedCost":
-        """Speedups measured by the autotuner (empty when untuned)."""
-        from repro.kernels import registry as kreg
-        return cls(kernel_speedup=kreg.kernel_speedups(registry))
-
     def __bool__(self) -> bool:
-        return bool(self.cell_step_s or self.kernel_speedup)
+        return bool(self.cell_step_s)
 
     # ----------------------------------------------------------- queries --
     def step_override(self, arch: str, shape_name: str,
                       mesh_label: str) -> Optional[float]:
         return self.cell_step_s.get(
             self.cell_key(arch, shape_name, mesh_label))
-
-    def _block_speedup(self, kernel: str, kind: str) -> float:
-        s = self.kernel_speedup.get(kernel, 1.0)
-        if kind == "train" and kernel == "flash_attention":
-            # the training path runs fwd + bwd kernels; average the
-            # measured ratios when both were tuned
-            s = (s + self.kernel_speedup.get("flash_attention_bwd", s)) / 2
-        return max(s, 1e-9)
-
-    def compute_scale(self, cfg: ModelConfig, shape: ShapeConfig) -> float:
-        """Multiplier on the analytic compute term, FLOPs-weighted: a
-        tuned kernel only accelerates the *core* FLOPs it executes
-        (attention scores, SSD recurrence, RG-LRU scan) — projections,
-        FFN, and logits are untouched XLA matmuls and keep weight 1.0.
-        Returns scaled_flops / total_flops over the whole forward."""
-        if not self.kernel_speedup:
-            return 1.0
-        B = shape.global_batch
-        S = 1 if shape.kind == "decode" else shape.seq_len
-        kind = shape.kind
-        d, H, K, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        total = scaled = 0.0
-        for blk in cfg.pattern:
-            core, s = 0.0, 1.0
-            if blk in (ATTN, ATTN_LOCAL):
-                w = cfg.local_window if blk == ATTN_LOCAL else 0
-                cache = shape.seq_len if kind == "decode" else 0
-                full = _attn_flops(cfg, B, S, window=w, kind=kind,
-                                   cache_len=cache)
-                proj = 2 * B * S * d * (H + 2 * K) * hd \
-                    + 2 * B * S * H * hd * d
-                core = max(0.0, full - proj)
-                s = self._block_speedup("flash_attention", kind)
-            elif blk == SSM:
-                full = _ssm_flops(cfg, B, S, kind)
-                sc = cfg.ssm
-                d_in = sc.expand * d
-                z = 2 * d_in + 2 * sc.n_groups * sc.d_state \
-                    + d_in // sc.head_dim
-                proj = 2 * B * S * d * z + 2 * B * S * d_in * d
-                core = max(0.0, full - proj)
-                s = self._block_speedup("ssd", kind)
-            elif blk == RGLRU:
-                full = _rglru_flops(cfg, B, S, kind)
-                r = cfg.rglru
-                core = min(full, 10.0 * B * S * (r.lru_width or d))
-                s = self._block_speedup("rglru", kind)
-            else:
-                full = 0.0
-            blk_total = full + _ffn_flops(cfg, B * S)
-            total += blk_total
-            scaled += blk_total - core + core / s
-        logits = 2.0 * B * S * d * cfg.padded_vocab    # unscaled
-        total += logits
-        scaled += logits
-        return scaled / total if total > 0 else 1.0
 
     def measure_cell(self, arch: str, shape_name: str, mesh_label: str,
                      step_s: float) -> None:
@@ -654,13 +580,11 @@ class CalibratedCost:
 
     # -------------------------------------------------------- persistence --
     def to_json(self) -> Dict[str, Any]:
-        return {"cell_step_s": dict(self.cell_step_s),
-                "kernel_speedup": dict(self.kernel_speedup)}
+        return {"cell_step_s": dict(self.cell_step_s)}
 
     @classmethod
     def from_json(cls, js: Mapping[str, Any]) -> "CalibratedCost":
-        return cls(cell_step_s=dict(js.get("cell_step_s", {})),
-                   kernel_speedup=dict(js.get("kernel_speedup", {})))
+        return cls(cell_step_s=dict(js.get("cell_step_s", {})))
 
 
 def kv_bytes_per_token(cfg: ModelConfig, dtype_bytes: int = 2) -> float:

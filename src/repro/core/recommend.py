@@ -162,25 +162,18 @@ def get_calibration() -> Optional[CalibratedCost]:
     return _calibration
 
 
-def calibrate_candidate(cand: Candidate, cfg: ModelConfig, arch: str,
-                        shape_name: str, shape: ShapeConfig,
+def calibrate_candidate(cand: Candidate, arch: str, shape_name: str,
                         cal: Optional[CalibratedCost]) -> Candidate:
-    """Re-price one analytic candidate from measurements (no-op without
-    a calibration layer or for infeasible candidates)."""
-    if cal is None or not cal or not cand.feasible:
+    """Re-price one analytic candidate from a measured cell: its step is
+    the measurement where one exists, analytic otherwise (and always for
+    infeasible candidates)."""
+    if cal is None or not cand.feasible:
         return cand
     measured = cal.step_override(arch, shape_name, cand.label)
-    terms = dict(cand.terms)
-    if measured is not None:
-        terms["measured"] = measured
-        return dataclasses.replace(cand, step_s=measured, terms=terms)
-    scale = cal.compute_scale(cfg, shape)
-    if scale == 1.0:
+    if measured is None:
         return cand
-    terms["compute"] = terms.get("compute", 0.0) * scale
-    step = max(terms.get("compute", 0.0), terms.get("memory", 0.0),
-               terms.get("collective", 0.0))
-    return dataclasses.replace(cand, step_s=step, terms=terms)
+    terms = dict(cand.terms, measured=measured)
+    return dataclasses.replace(cand, step_s=measured, terms=terms)
 
 
 def candidates(n_chips: int = 256, pods: int = 1
@@ -203,10 +196,9 @@ def recommend(arch: str, shape_name: str, *, n_chips: int = 256,
 
     When a ``calibration`` layer is supplied (or installed process-wide
     via ``set_calibration``) the analytic terms are re-priced from
-    measurements before ranking — measured cells override the whole step,
-    tuned-kernel speedups scale the compute term.  ``topology`` +
-    ``domain_chips`` (chips per drawer) apply the multi-tier admission
-    derate to candidates that must span drawers.
+    measurements before ranking — measured cells override the whole
+    step.  ``topology`` + ``domain_chips`` (chips per drawer) apply the
+    multi-tier admission derate to candidates that must span drawers.
     """
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
@@ -214,7 +206,7 @@ def recommend(arch: str, shape_name: str, *, n_chips: int = 256,
     cands = [calibrate_candidate(
                  _estimate(cfg, shape, dp, tp, pods,
                            topology=topology, domain_chips=domain_chips),
-                 cfg, arch, shape_name, shape, cal)
+                 arch, shape_name, cal)
              for dp, tp in candidates(n_chips, pods)]
     cands.sort(key=lambda c: c.step_s)
     return cands[:top]

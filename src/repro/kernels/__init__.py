@@ -1,3 +1,5 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels.  ``flash_attention_bwd`` is the train step's
+attention on the chip, chosen by ``repro.models.attention``;
+``paged_attention`` is the paged-decode kernel, not yet on the serving
+path; ``ref`` holds the pure-jnp oracles the tests hold both to.  Only
+``repro.models`` imports this package."""

@@ -19,7 +19,7 @@ Layout and grid:
     block copies of the pool (one ``in_spec`` per page slot — Pallas
     block shapes must be static, the page *ids* are not);
   * online-softmax running state (m, l, acc) lives in VMEM scratch
-    exactly as in ``flash_attention.py``; all G query heads of a GQA
+    exactly as in ``flash_attention_bwd.py``; all G query heads of a GQA
     group ride in one block.
 
 Masking is positional: slot ``t`` of sequence ``b`` is live iff
@@ -103,7 +103,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
     int32 page ids; lengths (B,) valid-token counts -> (B,H,D).
 
     ``block_k`` is fitted down to a multiple of the page size whose
-    page count divides P, so any tuned value is legal.
+    page count divides P, so any value is legal.
     """
     B, H, D = q.shape
     ps, K = k_pages.shape[1], k_pages.shape[2]

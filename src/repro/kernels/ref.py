@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets).
+"""Pure-jnp oracles for the Pallas kernels (the allclose targets).
 
 These delegate to the model-zoo reference implementations so the kernels
 are validated against exactly the math the framework trains/serves with.
@@ -8,8 +8,6 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.models.attention import decode_attention, full_attention
-from repro.models.rglru import rglru_scan
-from repro.models.ssm import ssd_chunked
 
 
 def attention_ref(q, k, v, *, causal=True, window=0, softcap=0.0):
@@ -37,13 +35,3 @@ def paged_attention_ref(q, k_pages, v_pages, tables, lengths, *,
     cache_pos = jnp.where(t < lengths[:, None], t, -1)
     return decode_attention(q[:, None], k, v, cache_pos,
                             softcap=softcap)[:, 0]
-
-
-def ssd_ref(x, dt, A, Bm, Cm, *, chunk=64, h0=None):
-    """Chunked SSD oracle: returns (y (B,S,H,P), h_final (B,H,N,P))."""
-    return ssd_chunked(x, dt, A, Bm, Cm, chunk=chunk, h0=h0)
-
-
-def rglru_ref(log_a, gated, h0=None):
-    """Linear recurrence oracle via associative scan: (B,S,W) -> (B,S,W)."""
-    return rglru_scan(log_a, gated, h0=h0)

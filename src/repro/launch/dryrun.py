@@ -110,9 +110,7 @@ def lower_cell(arch: str, shape_name: str, mesh, policy: PolicyConfig,
         flops = (costmodel.forward_flops(cfg, shape, with_logits=False)
                  + 2 * shape.global_batch * cfg.d_model * cfg.padded_vocab)
     else:  # decode
-        step = engine.make_decode_step(cfg, policy, mesh=mesh,
-                                       max_seq=shape.seq_len,
-                                       batch=shape.global_batch)
+        step = engine.make_decode_step(cfg, policy, mesh=mesh)
         pspec = pol.param_specs(ins["params"], cfg, policy, mesh_axes)
         cspec = pol.cache_specs(ins["caches"], policy, mesh_axes)
         tspec = pol.batch_specs(

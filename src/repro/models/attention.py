@@ -4,21 +4,22 @@ Paths:
   * ``full``       — materializes (S, T) scores; oracle + short sequences.
   * ``flash_xla``  — two-level blocked scan (online softmax), pure JAX. Never
                      materializes more than one (q_block, kv_block) score
-                     tile; lowers/compiles on any backend. This mirrors the
-                     Pallas kernel in ``repro.kernels.flash_attention`` and is
-                     the dry-run implementation.
+                     tile; lowers/compiles on any backend; the path of every
+                     call the kernels below do not take.
   * ``decode``     — single-token attention over a (possibly ring-buffered)
                      KV cache.
   * the Pallas kernels (``kernels.flash_attention_bwd``) — on the TPU, for
                      unsharded, unwindowed, unmasked calls whose lengths
                      the kernels' tiles divide (``kernel_takes``): forward
-                     and backward on chip, no score tile saved to HBM.
+                     and backward on chip, no score tile saved to HBM.  The
+                     one place that chooses the kernel and its tiles.
 
 All paths support GQA (H = K * G query groups), causal masking, and sliding
 windows. Shapes: q (B, S, H, D); k/v (B, T, Kh, D).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -35,8 +36,7 @@ NEG_INF = -1e30
 
 def _fit_block(block: int, dim: int) -> int:
     """Largest tile <= ``block`` that divides ``dim`` (bounded: at most
-    ``block`` decrements).  Mirrors kernels.registry.fit_block without a
-    cross-layer import."""
+    ``block`` decrements)."""
     b = max(1, min(int(block), int(dim)))
     while dim % b:
         b -= 1
@@ -122,8 +122,8 @@ def flash_attention_xla(q, k, v, *, causal=True, window=0, q_offset=0,
     B, S, H, D = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
-    # fit, don't assert: tuned/default tiles come from the step builder's
-    # build-time shape, but a served prompt can be any length <= capacity
+    # fit, don't assert: the tiles are fixed when the step is built, but
+    # a served prompt can be any length <= capacity
     q_block = _fit_block(q_block, S)
     kv_block = _fit_block(kv_block, T)
     nq, nk = S // q_block, T // kv_block
@@ -536,6 +536,16 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+@functools.lru_cache(maxsize=1)
+def default_interpret() -> bool:
+    """Whether the Pallas kernels run in the interpreter: everywhere but
+    on the TPU.  Kept apart from ``on_tpu`` so that a test can dispatch
+    as the TPU does while the kernels still interpret on the CPU.
+    Cached: the backend cannot change within a process, and the answer
+    is part of the jit cache key."""
+    return jax.default_backend() != "tpu"
+
+
 def kernel_takes(q, k, window: int, kv_mask) -> bool:
     """Whether an unsharded, uncached call runs the fused Pallas kernels:
     on the TPU, full (not windowed) attention, no key mask, and lengths
@@ -552,7 +562,6 @@ def flash_kernel_attention(q, k, v, *, causal=True, softcap=0.0):
     """The fused Pallas flash attention (``kernels.flash_attention_bwd``):
     forward and backward on chip, recomputing score tiles in the backward
     from the saved row statistics instead of keeping them in HBM."""
-    from repro.kernels.ops import default_interpret
     bq, bk = fab.kernel_blocks(q.shape[2] // k.shape[2], q.shape[3])
     return fab.flash_attention_vjp(q, k, v, causal, 0, softcap, bq, bk,
                                    default_interpret())

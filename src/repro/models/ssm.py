@@ -2,8 +2,7 @@
 
 Training/prefill uses the chunked SSD algorithm as a ``lax.scan`` over
 sequence chunks (quadratic attention-like math within a chunk; a rank-N
-recurrent state carries information between chunks). This exactly mirrors
-the Pallas kernel tiling in ``repro.kernels.ssd``. Decode is the linear
+recurrent state carries information between chunks). Decode is the linear
 recurrence h <- exp(dt·A) h + dt·B⊗x.
 
 Sharding design (the §Perf-driven layout): the input projections are

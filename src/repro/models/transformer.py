@@ -34,7 +34,7 @@ class ParallelCtx:
 @dataclasses.dataclass(frozen=True)
 class RunCtx:
     compute_dtype: Any = jnp.bfloat16
-    attn_impl: str = "xla"            # xla | full | pallas
+    attn_impl: str = "xla"            # xla | full
     attn_blocks: Tuple[int, int] = (512, 512)
     moe_impl: str = "sorted"          # dense | sorted | ep
     moe_capacity: Optional[int] = None

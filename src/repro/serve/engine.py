@@ -23,8 +23,7 @@ Two engines sit on top of the jitted steps:
     (``serve.kvcache``: shared page pool, block tables, prefix-hash
     reuse), an SLO-aware request scheduler (``serve.scheduler``) with
     chunked prefill interleaved against the decode batch, decode-step
-    batching keyed by the tuned-config registry's (B, 1, cache_len)
-    buckets, and per-request telemetry
+    batching in power-of-two batch buckets, and per-request telemetry
     (``cluster.telemetry.ServingStats``).
 """
 from __future__ import annotations
@@ -39,13 +38,20 @@ import jax.numpy as jnp
 from repro.cluster.telemetry import ServingStats
 from repro.configs.base import ATTN, ModelConfig, PolicyConfig, ShapeConfig
 from repro.core import policy as pol
-from repro.kernels.registry import bucket_pow2
 from repro.models import lm, scopes, transformer
-from repro.models.transformer import RunCtx
 from repro.serve import kvcache
 from repro.serve.scheduler import (DECODE, PREFILL, RequestScheduler,
                                    ServeRequest)
 from repro.train.trainer import make_run_ctx
+
+
+def bucket_pow2(n: int, floor: int = 32) -> int:
+    """Next power of two >= n (>= floor): the shape buckets of the
+    jitted steps, so one trace serves every size in a bucket."""
+    b = floor
+    while b < n:
+        b *= 2
+    return b
 
 
 # ---------------------------------------------------------------------------
@@ -56,9 +62,6 @@ def make_prefill_step(cfg: ModelConfig, policy: PolicyConfig, *,
                       bucketed: bool = False) -> Callable:
     """prefill(params, tokens) -> (last-token logits, caches).
 
-    The attention tiles come from the tuned-config registry keyed by the
-    prefill length (= cache capacity); defaults on a registry miss.
-
     ``bucketed=True`` returns ``prefill(params, tokens, length)`` for
     pow2-padded prompts: ``tokens`` (B, S_bucket) right-padded, ``length``
     (B,) int32 real lengths.  Padded columns are masked end to end —
@@ -67,7 +70,7 @@ def make_prefill_step(cfg: ModelConfig, policy: PolicyConfig, *,
     trace serves every prompt length in the bucket.
     """
     ctx = dataclasses.replace(
-        make_run_ctx(cfg, policy, mesh, seq_len=cache_capacity),
+        make_run_ctx(cfg, policy, mesh),
         cache_capacity=cache_capacity)
 
     def prefill(params, tokens):
@@ -95,17 +98,13 @@ def make_prefill_step(cfg: ModelConfig, policy: PolicyConfig, *,
     return prefill_bucketed if bucketed else prefill
 
 
-def make_decode_step(cfg: ModelConfig, policy: PolicyConfig, mesh=None,
-                     max_seq: Optional[int] = None,
-                     batch: Optional[int] = None) -> Callable:
+def make_decode_step(cfg: ModelConfig, policy: PolicyConfig,
+                     mesh=None) -> Callable:
     """decode(params, caches, tokens, positions) -> (logits, caches).
 
     tokens (B, 1) int32 (or (B, 1, d) embeddings); positions (B, 1) int32.
-    ``max_seq`` (the cache length) and ``batch`` key the tuned-config
-    lookup at the (B, 1, cache_len) decode bucket.
     """
-    ctx = make_run_ctx(cfg, policy, mesh, seq_len=max_seq, decode=True,
-                       batch=batch)
+    ctx = make_run_ctx(cfg, policy, mesh)
 
     def decode(params, caches, tokens, positions):
         logits, new_caches, _ = lm.forward(params, tokens, cfg, ctx,
@@ -154,8 +153,7 @@ class ServeEngine:
         self.max_seq = max_seq
         self.ctx_dtype = jnp.bfloat16 \
             if policy.compute_dtype == "bfloat16" else jnp.float32
-        self.decode = jax.jit(make_decode_step(cfg, policy, mesh,
-                                               max_seq=max_seq))
+        self.decode = jax.jit(make_decode_step(cfg, policy, mesh))
         self.prefill = jax.jit(
             make_prefill_step(cfg, policy, cache_capacity=max_seq,
                               mesh=mesh))
@@ -282,11 +280,8 @@ class AsyncServeEngine:
         self.stats = ServingStats()
         self.compile_s = 0.0           # accumulated warmup() compile time
         self._util_sum = 0.0           # sum of per-iteration utilization
-        # decode-shape bucket from the tuned-config registry vocabulary:
-        # jit cache keys and block lookups share it
-        ctx = make_run_ctx(cfg, policy, mesh, seq_len=max_seq, decode=True,
-                           batch=n_slots)
-        self.ctx = dataclasses.replace(ctx, cache_capacity=max_seq)
+        self.ctx = dataclasses.replace(make_run_ctx(cfg, policy, mesh),
+                                       cache_capacity=max_seq)
         self._iters = 0
         self.tracker = tracker
         self.track_every = max(int(track_every), 1)
@@ -310,8 +305,7 @@ class AsyncServeEngine:
             self.prefill = jax.jit(make_prefill_step(
                 cfg, policy, cache_capacity=max_seq, mesh=mesh,
                 bucketed=True))
-            self.decode = jax.jit(make_decode_step(
-                cfg, policy, mesh, max_seq=max_seq, batch=n_slots))
+            self.decode = jax.jit(make_decode_step(cfg, policy, mesh))
 
     # ------------------------------------------------------------ plumbing --
     def now(self) -> float:

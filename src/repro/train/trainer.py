@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, Mapping, Optional, Tuple
+from typing import Any, Callable, Dict, Mapping, Optional
 
 import jax
 import jax.numpy as jnp
@@ -60,37 +60,8 @@ def _dt(name: str):
             "float16": jnp.float16}[name]
 
 
-def resolve_attn_blocks(cfg: ModelConfig, policy: PolicyConfig,
-                        seq_len: Optional[int], *,
-                        decode: bool = False,
-                        batch: Optional[int] = None) -> Tuple[int, int]:
-    """Shape-keyed tuned-config lookup for the step builders' attention
-    tiles (the XLA flash path): measured (q_block, kv_block) when the
-    registry has the bucket, the historical (512, 512) otherwise.
-
-    ``decode=True`` keys the (B, 1, cache_len) decode shape instead of
-    the square prefill shape — ``seq_len`` is then the cache length and
-    ``batch`` the decode batch bucket — so serving decode steps resolve
-    their own tuned cells rather than borrowing prefill tiles."""
-    from repro.kernels import registry as kreg
-    if not seq_len:
-        return RunCtx.attn_blocks        # class default — no shape known
-    g = max(1, cfg.n_heads // max(cfg.n_kv_heads, 1))
-    if decode:
-        return kreg.decode_attention_blocks(
-            batch or 1, seq_len, cfg.head_dim, g,
-            _dt(policy.compute_dtype), cfg.causal, 0,
-            defaults=(1, RunCtx.attn_blocks[1]))
-    return kreg.attention_blocks(
-        seq_len, seq_len, cfg.head_dim, g,
-        _dt(policy.compute_dtype), cfg.causal, 0,
-        defaults=RunCtx.attn_blocks, kernel="flash_attention_xla")
-
-
 def make_run_ctx(cfg: ModelConfig, policy: PolicyConfig,
-                 mesh=None, *, seq_len: Optional[int] = None,
-                 decode: bool = False,
-                 batch: Optional[int] = None) -> RunCtx:
+                 mesh=None) -> RunCtx:
     moe_impl = "sorted"
     if (cfg.moe is not None and policy.ep and mesh is not None
             and policy.tp_axis in getattr(mesh, "shape", {})
@@ -100,8 +71,6 @@ def make_run_ctx(cfg: ModelConfig, policy: PolicyConfig,
     return RunCtx(
         compute_dtype=_dt(policy.compute_dtype),
         attn_impl=policy.attn_impl,
-        attn_blocks=resolve_attn_blocks(cfg, policy, seq_len,
-                                        decode=decode, batch=batch),
         moe_impl=moe_impl,
         remat=policy.remat,
         pctx=ParallelCtx(mesh=mesh, dp_axes=policy.dp_axes,
@@ -142,7 +111,9 @@ def state_specs(state: TrainState, cfg: ModelConfig, policy: PolicyConfig,
 # ---------------------------------------------------------------------------
 def make_loss_fn(cfg: ModelConfig, policy: PolicyConfig, mesh=None,
                  seq_len: Optional[int] = None) -> Callable:
-    ctx = make_run_ctx(cfg, policy, mesh, seq_len=seq_len)
+    """``seq_len`` is accepted for callers that build from a cell's
+    shape; the loss does not depend on it."""
+    ctx = make_run_ctx(cfg, policy, mesh)
     big_vocab = cfg.padded_vocab >= 32_768
 
     def loss_fn(params, batch):
@@ -195,11 +166,11 @@ def make_train_step(cfg: ModelConfig, policy: PolicyConfig,
     """Returns train_step(state, batch) -> (state, metrics).
 
     Lowers/compiles under any mesh; all sharding comes from in/out specs
-    (see ``jit_train_step`` and ``launch.dryrun``).  ``shape`` keys the
-    tuned-config lookup for the attention tiles; None keeps defaults.
+    (see ``jit_train_step`` and ``launch.dryrun``).  ``shape`` is
+    accepted for callers that build from a cell's shape; the step takes
+    its shapes from the batch it is traced with.
     """
-    seq_len = shape.seq_len if shape is not None else None
-    loss_fn = make_loss_fn(cfg, policy, mesh, seq_len=seq_len)
+    loss_fn = make_loss_fn(cfg, policy, mesh)
     mesh_axes = dict(getattr(mesh, "shape", {})) if mesh is not None else {}
     use_pod_exchange = (
         "pod" in mesh_axes and mesh_axes["pod"] > 1
@@ -232,7 +203,7 @@ def make_train_step(cfg: ModelConfig, policy: PolicyConfig,
     pod_policy = dataclasses.replace(
         policy, dp_axes=tuple(a for a in policy.dp_axes if a != "pod"),
         ep=False)
-    pod_loss_fn = make_loss_fn(cfg, pod_policy, mesh, seq_len=seq_len)
+    pod_loss_fn = make_loss_fn(cfg, pod_policy, mesh)
 
     def train_step(state: TrainState, batch):
 

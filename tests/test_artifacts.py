@@ -334,8 +334,8 @@ def test_every_result_artifact_is_schema_versioned(path):
 
 
 @pytest.mark.parametrize("bench", ["cluster_sim", "serve_bench",
-                                   "storage_bench", "kernel_tune",
-                                   "chaos_bench", "fabric_bench"])
+                                   "storage_bench", "chaos_bench",
+                                   "fabric_bench"])
 def test_bench_artifacts_record_their_run_id(bench):
     path = os.path.join(RESULTS_DIR, f"{bench}.json")
     if not os.path.exists(path):
@@ -393,8 +393,8 @@ def test_bench_trajectory_schema(path):
 
 
 @pytest.mark.parametrize("bench", ["cluster_sim", "serve_bench",
-                                   "storage_bench", "kernel_tune",
-                                   "chaos_bench", "fabric_bench"])
+                                   "storage_bench", "chaos_bench",
+                                   "fabric_bench"])
 def test_each_shipped_bench_has_a_seeded_trajectory(bench):
     art = os.path.join(RESULTS_DIR, f"{bench}.json")
     traj = os.path.join(RESULTS_DIR, f"BENCH_{bench}.json")

@@ -146,3 +146,103 @@ def test_roofline_dominant_term():
     r2 = _report_with_collectives(0.0)
     rl2 = costmodel.roofline(r2, sys_)
     assert rl2.dominant in ("compute", "memory")
+
+
+# ---------------------------------------------------------------------------
+# measured-cost calibration: a measured cell replaces the analytic step
+# ---------------------------------------------------------------------------
+def test_calibrated_utilization_stays_bounded():
+    """A measured cell far below the analytic compute bound must not
+    push busy fractions past 1 (review regression: AUU went negative)."""
+    from repro.cluster import TraceConfig, run_trace
+    from repro.core import recommend
+    cal = costmodel.CalibratedCost()
+    plan = recommend.recommend("qwen2-0.5b", "train_4k", n_chips=16,
+                               top=1)[0]
+    cal.measure_cell("qwen2-0.5b", "train_4k", plan.label,
+                     plan.step_s / 100.0)
+    rep = run_trace(TraceConfig(n_jobs=8, seed=2, calibration=cal))
+    assert 0.0 <= rep["auu"] <= 1.0
+    assert rep["accelerator_utilization"] <= 1.0
+
+
+def test_calibration_changes_recommend_ranking():
+    """A measured step time for a non-winning mesh must be able to
+    re-rank recommend()."""
+    from repro.core import recommend
+    arch, shape, chips = "qwen2-0.5b", "train_4k", 64
+    plain = recommend.recommend(arch, shape, n_chips=chips, top=2)
+    winner, runner_up = plain[0], plain[1]
+    cal = costmodel.CalibratedCost()
+    # measurement says the analytic runner-up actually runs 10x faster
+    cal.measure_cell(arch, shape, runner_up.label, winner.step_s / 10.0)
+    cald = recommend.recommend(arch, shape, n_chips=chips, top=2,
+                               calibration=cal)
+    assert cald[0].label == runner_up.label
+    assert cald[0].label != plain[0].label
+    assert cald[0].terms.get("measured") == pytest.approx(
+        winner.step_s / 10.0)
+    # the cell with no measurement keeps its analytic step and terms
+    other = [c for c in cald if c.label == winner.label]
+    assert other and other[0].step_s == winner.step_s
+    assert other[0].terms == winner.terms
+
+
+def test_set_calibration_reaches_existing_scheduler():
+    """Process-wide set_calibration() must be honored by schedulers
+    built before the call (review regression: construction-time
+    snapshot)."""
+    from repro.cluster.scheduler import Scheduler
+    from repro.core import recommend
+    from repro.core.topology import make_pool
+    sched = Scheduler(make_pool(n_local=8, n_switch=0, pods=1))
+    assert sched.calibration is None
+    cal = costmodel.CalibratedCost(
+        cell_step_s={"qwen2-0.5b|train_4k|8x1": 0.5})
+    recommend.set_calibration(cal)
+    try:
+        assert sched.calibration is cal
+    finally:
+        recommend.set_calibration(None)
+    assert sched.calibration is None
+
+
+def test_calibration_flows_into_scheduler_admission_pricing():
+    """The scheduler's plan (and therefore simulator pricing) uses the
+    measured step time, changing which mesh a job is admitted on."""
+    from repro.cluster.scheduler import Job, Scheduler
+    from repro.core import recommend
+    from repro.core.topology import make_pool
+
+    def best_plan(calibration):
+        pool = make_pool(n_local=64, n_switch=0, pods=1)
+        sched = Scheduler(pool, calibration=calibration)
+        job = Job(name="j", arch="qwen2-0.5b", shape_name="train_4k",
+                  n_chips=64)
+        assert sched.submit(job, 0.0)
+        return job.plan
+
+    plain = best_plan(None)
+    cal = costmodel.CalibratedCost()
+    # measure a different factorization as dramatically faster
+    alt = [c for c in recommend.recommend(
+        "qwen2-0.5b", "train_4k", n_chips=64, top=5)
+        if c.label != plain.label][0]
+    cal.measure_cell("qwen2-0.5b", "train_4k", alt.label,
+                     plain.step_s / 100.0)
+    cald = best_plan(cal)
+    assert cald.label == alt.label
+    assert cald.label != plain.label
+
+
+def test_calibration_json_round_trip():
+    cal = costmodel.CalibratedCost()
+    assert not cal
+    cal.measure_cell("mamba2-780m", "train_4k", "16x4", 1.25)
+    assert cal
+    js = cal.to_json()
+    assert js == {"cell_step_s": {"mamba2-780m|train_4k|16x4": 1.25}}
+    back = costmodel.CalibratedCost.from_json(js)
+    assert back == cal
+    assert back.step_override("mamba2-780m", "train_4k", "16x4") == 1.25
+    assert back.step_override("mamba2-780m", "train_4k", "8x8") is None

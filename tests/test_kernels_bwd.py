@@ -7,7 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_config, reduced
+from repro.configs import REGISTRY, get_config, reduced
+from repro.configs.base import ATTN, ATTN_LOCAL
 from repro.kernels.flash_attention_bwd import (flash_attention_vjp,
                                                kernel_blocks)
 from repro.kernels.ref import attention_ref
@@ -65,17 +66,6 @@ def test_flash_bwd_matches_oracle_grads(B, S, H, K, D, causal, window,
         else:
             err = np.linalg.norm(a - b) / np.linalg.norm(b)
             assert err < tol, (name, err)
-
-
-def test_flash_bwd_forward_matches_fwd_kernel():
-    from repro.kernels.flash_attention import flash_attention
-    q = jax.random.normal(K1, (1, 128, 4, 32))
-    k = jax.random.normal(K2, (1, 128, 2, 32))
-    v = jax.random.normal(K3, (1, 128, 2, 32))
-    a = flash_attention_vjp(q, k, v, True, 0, 0.0, 64, 64, True)
-    b = flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
-                        interpret=True)
-    np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
 
 
 def test_flash_bwd_block_independence():
@@ -157,3 +147,34 @@ def test_dispatch_takes_kernel_on_tpu(on_tpu, monkeypatch):
     want = _apply(BK)
     assert on_tpu == ["flash_attention_xla"]
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+
+
+def test_interpret_default_is_backend_derived():
+    # off the TPU the kernels run in the interpreter, whatever the
+    # dispatch predicate is patched to
+    assert attention.default_interpret() == (jax.default_backend() != "tpu")
+
+
+ATTN_ARCHS = [a for a in REGISTRY
+              if {ATTN, ATTN_LOCAL} & set(get_config(a).pattern)]
+
+
+@pytest.mark.parametrize("arch", ATTN_ARCHS)
+def test_dispatch_at_published_widths(monkeypatch, arch):
+    """On the TPU every attention model at its published widths takes the
+    kernel at a length its ``block_k`` divides and keeps the XLA path at
+    one it does not; windowed layers always keep the XLA path."""
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cfg = get_config(arch)
+    D = cfg.head_dim
+    bk = kernel_blocks(cfg.n_heads // cfg.n_kv_heads, D)[1]
+
+    def takes(S, blk):
+        q = jax.ShapeDtypeStruct((1, S, cfg.n_heads, D), BF16)
+        k = jax.ShapeDtypeStruct((1, S, cfg.n_kv_heads, D), BF16)
+        window = cfg.local_window if blk == ATTN_LOCAL else 0
+        return attention.kernel_takes(q, k, window, None)
+
+    for blk in {ATTN, ATTN_LOCAL} & set(cfg.pattern):
+        assert takes(4 * bk, blk) == (blk == ATTN), blk
+        assert not takes(4 * bk + bk // 2, blk), blk

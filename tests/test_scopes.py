@@ -109,10 +109,9 @@ def test_attn_core_labels_flash_kernels_in_every_pass(monkeypatch):
     step is lowered for the TPU (no chip needed) with the backend
     predicates turned to the TPU's answers; each custom call's location
     is the ``op_name`` it compiles to."""
-    from repro.kernels import ops
     from repro.models import attention
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
-    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    monkeypatch.setattr(attention, "default_interpret", lambda: False)
     cfg = reduced(get_config("qwen2-0.5b"))
     seq = attention.fab.kernel_blocks(
         cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)[1]

@@ -17,6 +17,7 @@ from repro.models import lm
 from repro.serve import (SLO, AsyncServeEngine, PageError, PagePool,
                          Request, RequestScheduler, ServeEngine,
                          ServeRequest)
+from repro.serve.engine import bucket_pow2
 from repro.serve.scheduler import DECODE
 from repro.train.trainer import make_run_ctx
 
@@ -130,7 +131,7 @@ def test_retained_pages_evicted_lru(small_lm):
 def test_chunked_prefill_matches_one_shot(small_lm):
     cfg, params = small_lm
     ctx = dataclasses.replace(
-        make_run_ctx(cfg, POLICY, None, seq_len=32), cache_capacity=32)
+        make_run_ctx(cfg, POLICY, None), cache_capacity=32)
     toks = jnp.asarray([_prompt(3, 21, cfg.vocab_size)])
     h1, c1, _ = lm.forward(params, toks, cfg, ctx, caches="init",
                            return_hidden=True)
@@ -156,7 +157,7 @@ def test_chunked_prefill_matches_windowed(small_lm):
         cfg, block_pattern=("attn_local",) * cfg.n_layers, local_window=6)
     params = lm.init_lm(jax.random.PRNGKey(1), cfg)
     ctx = dataclasses.replace(
-        make_run_ctx(cfg, POLICY, None, seq_len=32), cache_capacity=32)
+        make_run_ctx(cfg, POLICY, None), cache_capacity=32)
     toks = jnp.asarray([_prompt(4, 20, cfg.vocab_size)])
     h1, _, _ = lm.forward(params, toks, cfg, ctx, caches="init",
                           return_hidden=True)
@@ -242,6 +243,16 @@ def test_dense_mode_serves_recurrent_arch(rng):
         eng.submit(r)
     eng.run()
     assert all(r.done for r in reqs)
+
+
+def test_seq_dims_bucket_to_pow2():
+    """The jitted steps' shape buckets: the next power of two, from the
+    floor up, so one trace serves every size in a bucket."""
+    assert bucket_pow2(384) == bucket_pow2(512) == 512
+    assert bucket_pow2(513) == 1024
+    assert bucket_pow2(3) == 32                   # the default floor
+    assert [bucket_pow2(n, floor=1) for n in (1, 2, 3, 5, 8)] == \
+        [1, 2, 4, 8, 8]
 
 
 def test_dense_prefill_traces_constant_across_prompt_lengths(rng):

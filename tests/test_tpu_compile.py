@@ -39,17 +39,13 @@ def topo():
 def one_chip(topo):
     """Sharding on chip 0, with the persistent compilation cache off (a
     compile for a described chip is written to it but cannot be read
-    back without one) and the tuned-block registry empty (its entries
-    were tuned on the CPU, so the chip runs the defaults)."""
+    back without one)."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     from jax.sharding import SingleDeviceSharding
-    from repro.kernels import registry
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    registry.set_registry(None)
     yield SingleDeviceSharding(topo.devices[0])
-    registry.reset_registry()
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
 
@@ -100,11 +96,10 @@ def xla_train_step(one_chip, smoke):
 def kernel_train_step(one_chip, smoke):
     """The same step as the chip builds it: attention through the Pallas
     flash kernels, the backend predicates turned to the TPU's answers."""
-    from repro.kernels import ops
     from repro.models import attention
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(attention, "on_tpu", lambda: True)
-        mp.setattr(ops, "default_interpret", lambda: False)
+        mp.setattr(attention, "default_interpret", lambda: False)
         return _smoke_train_step(one_chip, smoke)
 
 
@@ -261,9 +256,14 @@ KV = (4, 2048, 2, 64)
 
 
 def _flash_fwd():
-    from repro.kernels.flash_attention import flash_attention
-    return (functools.partial(flash_attention, causal=True, interpret=False),
-            ((Q, BF16), (KV, BF16), (KV, BF16)))
+    """The kernel's forward alone: the dense-prefill program."""
+    from repro.kernels.flash_attention_bwd import (flash_attention_vjp,
+                                                   kernel_blocks)
+    bq, bk = kernel_blocks(Q[2] // KV[2], Q[3])
+
+    def forward(q, k, v):
+        return flash_attention_vjp(q, k, v, True, 0, 0.0, bq, bk, False)
+    return forward, ((Q, BF16), (KV, BF16), (KV, BF16))
 
 
 def _flash_vjp():
@@ -285,21 +285,6 @@ def _paged_decode():
             (((4, 14, 64), F32), pages, pages, ((4, 64), I32), ((4,), I32)))
 
 
-def _ssd():
-    # mamba2-780m: 48 heads of 64, one group, state 128, chunk 256
-    from repro.kernels.ssd import ssd
-    return (functools.partial(ssd, chunk=256, interpret=False),
-            (((1, 2048, 48, 64), BF16), ((1, 2048, 48), F32), ((48,), F32),
-             ((1, 2048, 1, 128), BF16), ((1, 2048, 1, 128), BF16)))
-
-
-def _rglru():
-    from repro.kernels.rglru import rglru
-    seq = ((1, 2048, 2560), F32)
-    return (functools.partial(rglru, block_seq=128, interpret=False),
-            (seq, seq))
-
-
 def _refused(reason, raises=Exception):
     return pytest.mark.xfail(strict=True, reason=reason, raises=raises)
 
@@ -311,12 +296,6 @@ def _refused(reason, raises=Exception):
         "the Pallas TPU lowering requires the last two dims of a block "
         "shape divisible by 8 and 128 or equal to the array's: page "
         "block (1, 16, 1, 64) of pages (256, 16, 2, 64)", ValueError)),
-    pytest.param(_ssd, id="ssd", marks=_refused(
-        "Unimplemented primitive in Pallas TPU lowering: cumsum",
-        NotImplementedError)),
-    pytest.param(_rglru, id="rglru", marks=_refused(
-        "Unimplemented primitive in Pallas TPU lowering: dynamic_slice",
-        NotImplementedError)),
 ])
 def test_pallas_kernel_compiles_for_tpu(one_chip, kernel):
     fn, shapes = kernel()

@@ -299,7 +299,7 @@ def test_check_perf_cli_empty_dir_passes(tmp_path):
 def test_check_perf_demo_survives_spiky_rows_and_wide_bands(tmp_path):
     """Self-test regression: the synthetic degradation must beat the
     gate's *median* baseline and each metric's *own* band.  A newest
-    row sitting above the median (kernel_tune's 6,6,7 case counts) or
+    row sitting above the median (case counts of 6, 6, 7) or
     a wide custom band (serve_bench's wall-clock throughput at 0.5)
     used to absorb the flat 20%-off-the-last-row nudge and falsely
     fail the demo."""
