@@ -1,9 +1,21 @@
 """Mamba-2 (SSD, state-space duality) mixer — arXiv:2405.21060.
 
-Training/prefill uses the chunked SSD algorithm as a ``lax.scan`` over
-sequence chunks (quadratic attention-like math within a chunk; a rank-N
-recurrent state carries information between chunks). Decode is the linear
-recurrence h <- exp(dt·A) h + dt·B⊗x.
+Training/prefill uses the chunked SSD algorithm (quadratic attention-like
+math within a chunk; a rank-N recurrent state carries information between
+chunks), along one of two paths, chosen per call in ``kernel_takes``:
+
+  * on the TPU, at widths the tiles fit (``kernels.ssd_chunk.fits``: a
+    chunk of whole 128-lane tiles, heads that divide 128 lanes, a state
+    of whole lane tiles, a head block that divides the call's local heads
+    of a group), the Pallas kernel pair ``kernels.ssd_chunk``, forward
+    and backward, which keeps each chunk's L x L tiles in VMEM;
+  * everywhere else — every CPU run, and widths the tiles do not fit,
+    such as the reduced test configs — the ``lax.scan`` over chunks
+    below.  Off the TPU it is the reference path the kernels are tested
+    against (in interpret mode).
+
+Decode is the linear recurrence h <- exp(dt·A) h + dt·B⊗x
+(``ssd_decode_step``), on every backend.
 
 Sharding design (the §Perf-driven layout): the input projections are
 SPLIT per stream (z / x / B / C / dt) with per-stream causal convs —
@@ -27,17 +39,29 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import layers, scopes
+from repro.kernels import ssd_chunk
+from repro.models import attention, layers, scopes
 
 
 # ---------------------------------------------------------------------------
 # chunked SSD core
 # ---------------------------------------------------------------------------
+def kernel_takes(x, Bm, chunk: int) -> bool:
+    """Whether a call of ``ssd_chunked`` runs the Pallas SSD kernels: on
+    the TPU, with x (B,S,H,P) and B (B,S,G,N) at local widths and a chunk
+    that the kernels' tiles fit.  Any initial state is taken.  Every
+    other call keeps the XLA chunk scan."""
+    return attention.on_tpu() and ssd_chunk.fits(
+        x.shape[2], x.shape[3], Bm.shape[2], Bm.shape[3], chunk)
+
+
 @scopes.scoped(scopes.SSD)
 def ssd_chunked(x, dt, A, Bm, Cm, *, chunk: int, h0=None):
-    """Returns (y (B,S,H,P), h_final (B,H,N,P)). All math fp32.
+    """Returns (y (B,S,H,P), h_final (B,H,N,P)), both f32; the decay,
+    state and accumulation math is f32 on either path
+    (``kernel_takes``).
 
-    Under differentiation the chunk scan saves, per chunk, only its
+    Under differentiation the XLA chunk scan saves, per chunk, only its
     inputs (the chunk's x, dt, B, C) and the state ``h`` carried into
     it: no (B, L, L, H) tile is stacked over chunks; the backward
     recomputes each chunk's tiles where it consumes them.  A call that
@@ -56,6 +80,21 @@ def ssd_chunked(x, dt, A, Bm, Cm, *, chunk: int, h0=None):
         Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0), (0, 0)))
         S = S + pad
     nc = S // chunk
+
+    if kernel_takes(x, Bm, chunk):
+        # x, B and C reach the kernels in the dtype the step hands the
+        # core; the within-chunk cumulative log-decay is computed here
+        dtype = jnp.result_type(x, Bm, Cm)
+        dt = dt.astype(jnp.float32)
+        a = dt * A.astype(jnp.float32)
+        s = jnp.cumsum(a.reshape(Bsz, nc, chunk, H), axis=2).reshape(
+            Bsz, S, H)
+        h0 = (jnp.zeros((Bsz, H, N, P), jnp.float32) if h0 is None
+              else h0.astype(jnp.float32))
+        y, h_final = ssd_chunk.ssd_chunk_vjp(
+            x.astype(dtype), dt, s, Bm.astype(dtype), Cm.astype(dtype), h0,
+            chunk, attention.default_interpret())
+        return y[:, :S_orig], h_final
 
     x = x.astype(jnp.float32)
     dt = dt.astype(jnp.float32)
@@ -110,25 +149,24 @@ def ssd_chunked(x, dt, A, Bm, Cm, *, chunk: int, h0=None):
 
 
 def ssd_sharded(x, dt, A, Bm, Cm, *, chunk: int, mesh, dp_axes, tp_axis):
-    """SSD core under shard_map: batch over dp, heads over tp.
+    """SSD core under shard_map over every mesh axis: batch over dp and
+    heads over tp where they divide (mamba2: H=48 = 16x3), replicated
+    over the other axes.
 
     Inside the manual region every tensor is local, so the chunk scan
-    can emit no collectives.  Requires H % tp == 0 (mamba2: 48 = 16x3);
-    falls back to the plain path otherwise.  B/C (grouped, G=1) are
-    replicated over tp; dt/A/D head-tensors are tp-sliced at entry.
+    can emit no collectives and the Pallas kernels, which XLA cannot
+    partition, may run.  B/C (grouped, G=1) are replicated over tp;
+    dt/A/D head-tensors are tp-sliced at entry.
     """
     B, S, H, P = x.shape
     tp = mesh.shape.get(tp_axis, 1) if tp_axis else 1
     dp = tuple(a for a in dp_axes if mesh.shape.get(a, 1) > 1)
-    n_dp = 1
-    for a in dp:
-        n_dp *= mesh.shape[a]
-    if (tp > 1 and H % tp) or (n_dp > 1 and B % n_dp):
-        return ssd_chunked(x, dt, A, Bm, Cm, chunk=chunk)
+    if B % math.prod(mesh.shape[a] for a in dp):
+        dp = ()
 
     from jax.sharding import PartitionSpec as Pspec
     dp_e = (dp if len(dp) > 1 else dp[0]) if dp else None
-    tp_e = tp_axis if tp > 1 else None
+    tp_e = tp_axis if tp > 1 and H % tp == 0 else None
     sx = Pspec(dp_e, None, tp_e, None)
     sdt = Pspec(dp_e, None, tp_e)
     sA = Pspec(tp_e)
@@ -139,13 +177,12 @@ def ssd_sharded(x, dt, A, Bm, Cm, *, chunk: int, mesh, dp_axes, tp_axis):
     def body(xl, dtl, Al, Bl, Cl):
         return ssd_chunked(xl, dtl, Al, Bl, Cl, chunk=chunk)
 
-    manual = frozenset(dp) | ({tp_axis} if tp > 1 else set())
-    if not manual:
-        return ssd_chunked(x, dt, A, Bm, Cm, chunk=chunk)
     already = layers.manual_axes()
+    manual = frozenset(mesh.axis_names) - already
+    if not manual:
+        return body(x, dt, A, Bm, Cm)
     return jax.shard_map(
-        body, mesh=None if already else mesh,
-        axis_names=manual - already if already else manual,
+        body, mesh=None if already else mesh, axis_names=manual,
         in_specs=(sx, sdt, sA, sBC, sBC),
         out_specs=(sy, sh), check_vma=False,
     )(x, dt, A, Bm, Cm)

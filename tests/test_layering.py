@@ -1,6 +1,7 @@
 """Imports point one way: only ``repro.models`` imports ``repro.kernels``.
-The kernel on the train path is chosen in one place
-(``models.attention``); no other package reaches down to the kernels."""
+Each kernel on the train path is chosen in one place (attention in
+``models.attention``, the SSD core in ``models.ssm``); no other package
+reaches down to the kernels."""
 import ast
 import os
 

@@ -202,7 +202,40 @@ V5E_USABLE = 15.75 * 2**30
 
 @pytest.fixture(scope="module")
 def mamba2_zero3_four_chips(topo, one_chip):
+    """The cell's step as a CPU-driven trace builds it: the SSD core
+    through the XLA chunk scan."""
     return _mamba2_zero3_step(topo.devices)
+
+
+@pytest.fixture(scope="module")
+def mamba2_kernel_four_chips(topo, one_chip):
+    """The same step as the chip builds it: the SSD core through the
+    Pallas kernels, the backend predicates turned to the TPU's answers."""
+    from repro.models import attention
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(attention, "on_tpu", lambda: True)
+        mp.setattr(attention, "default_interpret", lambda: False)
+        return _mamba2_zero3_step(topo.devices)
+
+
+def _ssd_kernel_calls(compiled):
+    """op_name of every Pallas call of the step, by kernel name."""
+    calls = {}
+    for ln in compiled.as_text().splitlines():
+        if "custom-call(" in ln and "tpu_custom_call" in ln:
+            name = re.search(r"%(\S+) = ", ln).group(1)
+            calls[name] = re.search(r'op_name="([^"]*)"', ln).group(1)
+    return calls
+
+
+def _passes(op_names):
+    """(forward, block-remat recompute, backward) of ``op_names``."""
+    fwd = [n for n in op_names
+           if not n.startswith("jit(train_step)/transpose")]
+    remat = [n for n in op_names if "rematted_computation" in n]
+    bwd = [n for n in op_names if n.startswith("jit(train_step)/transpose")
+           and "rematted_computation" not in n]
+    return fwd, remat, bwd
 
 
 def test_mamba2_zero3_train_step_fits_four_chips(mamba2_zero3_four_chips):
@@ -212,21 +245,39 @@ def test_mamba2_zero3_train_step_fits_four_chips(mamba2_zero3_four_chips):
     assert _total_bytes(mamba2_zero3_four_chips) <= V5E_USABLE, m
 
 
-def test_mamba2_ssd_dots_are_scoped_in_every_pass(mamba2_zero3_four_chips):
-    """Every matrix product of the chunk scan (a loop inside the layer
-    scan) is under ``ssd``: in the forward pass, the block-remat recompute
-    and the backward pass, four products each way at least."""
-    text = mamba2_zero3_four_chips.as_text()
-    dots = [re.search(r'op_name="([^"]*)"', ln).group(1)
-            for ln in text.splitlines()
-            if re.search(r" (dot|convolution)\(", ln) and "op_name=" in ln]
-    scan = [n for n in dots if n.count("while/body") >= 2]
-    assert scan and all("/ssd/" in n for n in scan), scan
-    fwd = [n for n in scan if not n.startswith("jit(train_step)/transpose")]
-    remat = [n for n in scan if "rematted_computation" in n]
-    bwd = [n for n in scan if n.startswith("jit(train_step)/transpose")
-           and "rematted_computation" not in n]
-    assert len(fwd) >= 4 and len(remat) >= 4 and len(bwd) >= 8, scan
+def test_mamba2_ssd_dots_are_scoped_in_every_pass(
+        mamba2_kernel_four_chips):
+    """On the chip every matrix product of the SSD core is inside the
+    Pallas kernels, and every kernel call is under ``ssd``: in the
+    forward pass, the block-remat recompute and the backward pass."""
+    calls = _ssd_kernel_calls(mamba2_kernel_four_chips)
+    ssd = [n for k, n in calls.items() if k.startswith("ssd_chunk_")]
+    assert ssd and all("/ssd/" in n for n in ssd), calls
+    fwd, remat, bwd = _passes(ssd)
+    assert len(fwd) >= 1 and len(remat) >= 1 and len(bwd) >= 1, calls
+
+
+def test_mamba2_step_runs_the_ssd_kernels(mamba2_kernel_four_chips):
+    """The cell's step compiled as the chip builds it: the forward
+    kernel in the forward pass and in the block-remat recompute, the
+    backward kernel in the backward pass, each once per layer scan and
+    under ``/ssd/``; no chunk ``while`` loop is left under ``ssd``; the
+    step fits and its temporaries stay under 5 GiB per chip."""
+    calls = _ssd_kernel_calls(mamba2_kernel_four_chips)
+    fwd, remat, bwd = _passes(list(calls.values()))
+    kernel = lambda names, k: [n for n in names if f"/{k}/" in n]
+    assert len(kernel(fwd, "ssd_chunk_fwd")) == 1, calls
+    assert len(kernel(remat, "ssd_chunk_fwd")) == 1, calls
+    assert len(kernel(bwd, "ssd_chunk_bwd")) == 1, calls
+    assert len(calls) == 3 and all("/ssd/" in n for n in calls.values())
+    loops = [n for n in re.findall(r' while\(.*op_name="([^"]*)"',
+                                   mamba2_kernel_four_chips.as_text())
+             if "/ssd/" in n]
+    assert not loops, loops
+    m = mamba2_kernel_four_chips.memory_analysis()
+    assert m.alias_size_in_bytes > 0
+    assert _total_bytes(mamba2_kernel_four_chips) <= V5E_USABLE, m
+    assert m.temp_size_in_bytes <= 5 * 2**30, m.temp_size_in_bytes / 2**30
 
 
 def test_mamba2_ssd_scan_stacks_no_tiles(topo, mamba2_zero3_four_chips):
